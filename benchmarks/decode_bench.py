@@ -58,14 +58,11 @@ _CHILD = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
-import numpy as np
-import jax
-from jax.sharding import Mesh
 from repro.collectives.engine import CollectiveEngine
+from repro.launch.mesh import make_mesh
 from repro.obs.replay import measure_signature
 
-devs = np.array(jax.devices()).reshape(%(mesh)s)
-mesh = Mesh(devs, %(axes)s)
+mesh = make_mesh(%(mesh)s, %(axes)s)
 eng = CollectiveEngine(persist=False)
 out = {}
 for op in %(ops)s:
@@ -179,6 +176,9 @@ def _replay():
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                      "src")
     env["REPRO_RESTORE_TOPOLOGY"] = "0"
+    # a replay on virtual CPU devices: the child never reaches for an
+    # accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", child], env=env,
                           capture_output=True, text=True, timeout=1500)
     if proc.returncode != 0:

@@ -41,8 +41,14 @@ IMBALANCE_BOUND = 1.5
 def _fleet_serve(cfg, params, trace, *, n_replicas, router, batch,
                  max_len, block_size, prefill_chunk, seed, num_blocks,
                  queue_cap=None):
+    from repro.launch.roofline import V5E
     from repro.serving import Request
-    from repro.serving.fleet import AdmissionConfig, FleetServer
+    from repro.serving.fleet import AdmissionConfig, CostRouter, FleetServer
+
+    if router == "cost":
+        # counters only: routing is scale-free in the rate, so price at
+        # the v5e peak whatever device runs this
+        router = CostRouter(cfg, peak_flops=V5E.flops)
 
     fleet = FleetServer(
         cfg, params, n_replicas, batch, max_len, router=router,

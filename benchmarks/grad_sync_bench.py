@@ -45,7 +45,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(devices)d"
 import json, functools
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.collectives.api import (allreduce_inside, allreduce_multi_inside,
                                    select_algorithm)
 from repro.launch.roofline import parse_collective_bytes, collective_total
@@ -55,7 +56,7 @@ if FABRIC_SPEC:
     from repro.launch.train import install_fabric_topology
     install_fabric_topology(FABRIC_SPEC)
 
-mesh = jax.make_mesh(%(mesh_shape)s, %(mesh_axes)s)
+mesh = make_mesh(%(mesh_shape)s, %(mesh_axes)s)
 AXES = ("pod", "data")
 PLAN_SHAPES = %(plan_shapes)s
 
@@ -81,8 +82,8 @@ for nbytes in %(bucket_sizes)s:
     per_size = {}
     for name in %(variants)s:
         fn = shard_map(variant(name, nbytes), mesh=mesh, in_specs=spec,
-                       out_specs=spec, check_rep=False)
-        with mesh:
+                       out_specs=spec, check_vma=False)
+        with jax.set_mesh(mesh):
             compiled = jax.jit(fn).lower(
                 jax.ShapeDtypeStruct((n,), jnp.float32)).compile()
         coll = parse_collective_bytes(compiled.as_text())
@@ -165,6 +166,9 @@ def run(small: bool = False, verbose: bool = True,
     # gated counters must not depend on a machine-local calibration:
     # the child prices with the declared constants only
     env["REPRO_RESTORE_TOPOLOGY"] = "0"
+    # compiled-HLO counters on virtual CPU devices: the child never
+    # reaches for an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", child], env=env,
                           capture_output=True, text=True, timeout=1500)
     if proc.returncode != 0:

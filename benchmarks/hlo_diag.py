@@ -35,7 +35,7 @@ def diagnose(arch: str, shape_name: str, mesh_kind: str = "pod",
     policy = rules.for_mesh(mesh, fsdp=fsdp)
     model_layers.set_inner_unroll(True)
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             compiled = _lower_cell(cfg, shape, mesh, policy, microbatches,
                                    remat, unroll=True).compile()
     finally:

@@ -42,7 +42,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, functools
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.collectives.api import all_to_all_multi_inside, get_engine
 from repro.launch.roofline import parse_collective_bytes, collective_total
 
@@ -51,14 +52,14 @@ if FABRIC_SPEC:
     from repro.launch.train import install_fabric_topology
     install_fabric_topology(FABRIC_SPEC)
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 AXES = ("pod", "data")
 P_WORLD = 8
 
 def compiled_counters(fn, x):
     smfn = shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
-                     check_rep=False)
-    with mesh:
+                     check_vma=False)
+    with jax.set_mesh(mesh):
         compiled = jax.jit(smfn).lower(
             jax.ShapeDtypeStruct(x.shape, x.dtype)).compile()
     coll = parse_collective_bytes(compiled.as_text())
@@ -91,7 +92,7 @@ args = (jax.random.normal(ks[0], (G, gs, D), jnp.float32),
         jax.random.normal(ks[4], (E, F, D)) * 0.1)
 fwd = {}
 for name in ("lax", "auto"):
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(functools.partial(
             moe_ffn_ep, top_k=K, algorithm=name)).lower(*args).compile()
     coll = parse_collective_bytes(compiled.as_text())
@@ -140,6 +141,9 @@ def run(verbose: bool = True, fabric_spec: str | None = None):
     # the child prices with the declared constants only, matching the
     # stock-fabric engine _model_plans compares against
     env["REPRO_RESTORE_TOPOLOGY"] = "0"
+    # compiled-HLO counters on virtual CPU devices: the child never
+    # reaches for an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", child], env=env,
                           capture_output=True, text=True, timeout=1500)
     if proc.returncode != 0:

@@ -41,7 +41,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, functools
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.collectives.api import allreduce_multi_inside
 from repro.launch.roofline import parse_collective_bytes, collective_total
 
@@ -50,7 +51,7 @@ if FABRIC_SPEC:
     from repro.launch.train import install_fabric_topology
     install_fabric_topology(FABRIC_SPEC)
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 AXES = ("pod", "data")
 
 results = {}
@@ -61,8 +62,8 @@ for nbytes in %(bucket_sizes)s:
         fn = shard_map(functools.partial(allreduce_multi_inside,
                                          axes=AXES, algorithm=name),
                        mesh=mesh, in_specs=P(), out_specs=P(),
-                       check_rep=False)
-        with mesh:
+                       check_vma=False)
+        with jax.set_mesh(mesh):
             compiled = jax.jit(fn).lower(
                 jax.ShapeDtypeStruct((n,), jnp.float32)).compile()
         coll = parse_collective_bytes(compiled.as_text())
@@ -126,6 +127,9 @@ def run(verbose: bool = True):
         # gated counters must not depend on a machine-local
         # calibration: the child prices with the declared constants
         env["REPRO_RESTORE_TOPOLOGY"] = "0"
+        # compiled-HLO counters on virtual CPU devices: the child never
+        # reaches for an accelerator the parent may hold
+        env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.run([sys.executable, "-c", child], env=env,
                               capture_output=True, text=True,
                               timeout=1500)

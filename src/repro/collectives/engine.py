@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.autogen import autogen_tree, cache_dir, compute_tables
 from repro.core.model import (Fabric, FabricTopology, TPU_V5E_AXIS,
@@ -168,7 +168,7 @@ def measure_ppermute(mesh: Mesh, axis: str,
         x = jnp.zeros((n,), jnp.float32)
 
         fn = shard_map(lambda v: lax.ppermute(v, axis, perm), mesh=mesh,
-                       in_specs=P(), out_specs=P(), check_rep=False)
+                       in_specs=P(), out_specs=P(), check_vma=False)
         jitted = jax.jit(fn)
         jitted(x).block_until_ready()          # compile
         best = float("inf")
@@ -1548,7 +1548,8 @@ class CollectiveEngine:
                                     algorithm: str = "auto",
                                     block_m: Optional[int] = None,
                                     block_n: Optional[int] = None,
-                                    interpret: bool = True) -> jax.Array:
+                                    interpret: Optional[bool] = None
+                                    ) -> jax.Array:
         """``reduce_scatter(x @ w)`` over ``axes`` with the GEMM tiles
         overlapping the ring's wire time, run inside shard_map.
 
@@ -1565,7 +1566,8 @@ class CollectiveEngine:
         degenerates to the engine's chunk-overlapped reduce-scatter
         over the same axes -- the same opt-in flag covers both sites.
         Shapes the ring cannot tile (M not divisible by P) fall back to
-        the gathered path."""
+        the gathered path.  ``interpret=None`` takes the backend's Pallas
+        mode (``kernels.ops.default_interpret``)."""
         from repro.kernels import fused_matmul_rs as fk
         axes = (axes,) if isinstance(axes, str) else tuple(axes)
         if w is None:
@@ -1616,7 +1618,7 @@ class CollectiveEngine:
     def _wrap(self, fn: Callable[[jax.Array], jax.Array], mesh: Mesh,
               in_spec: P, out_spec: P) -> Callable[[jax.Array], jax.Array]:
         return shard_map(fn, mesh=mesh, in_specs=in_spec,
-                         out_specs=out_spec, check_rep=False)
+                         out_specs=out_spec, check_vma=False)
 
     def allreduce(self, x: jax.Array, mesh: Mesh, axis: str,
                   algorithm: str = "auto") -> jax.Array:

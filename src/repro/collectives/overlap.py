@@ -33,7 +33,7 @@ from typing import Any, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.collectives.api import get_engine
 from repro.collectives.engine import CollectiveEngine
@@ -115,7 +115,7 @@ def bucketed_allreduce(grads, mesh: Mesh, axes: Tuple[str, ...] = ("data",),
     spec = P()
     fn = shard_map(lambda *bs: tuple(reduce_bucket(b) for b in bs),
                    mesh=mesh, in_specs=spec, out_specs=spec,
-                   check_rep=False)
+                   check_vma=False)
     # reverse order: last layers' buckets first (they finish backward
     # earliest -> overlap with remaining backward compute)
     reduced = list(fn(*buckets[::-1]))[::-1]

@@ -90,7 +90,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """[B, H, S, D] x [B, Hkv, S, D] -> [B, H, S, D] attention."""
     b, h, s, d = q.shape
     hkv = k.shape[1]

@@ -23,9 +23,9 @@ compiler overlaps them; after ``P-1`` rotations device ``d`` holds
 ``sum_d' partial_d'[d]`` -- exactly ``lax.psum_scatter(x @ w, axis,
 tiled=True)``.
 
-The per-block GEMM is a Pallas tiled matmul (fp32 accumulation,
-``interpret=True`` default so it runs everywhere; flip off on real
-TPUs).  The oracle lives in ``kernels/ref.py``
+The per-block GEMM is a Pallas tiled matmul (fp32 accumulation;
+native on TPU, interpreted elsewhere, as ``ops.default_interpret``
+decides).  The oracle lives in ``kernels/ref.py``
 (``fused_matmul_rs_ref``); ``matmul_then_rs`` is the unfused gathered
 fallback used off-TPU and for shapes the ring cannot tile (M not
 divisible by P).
@@ -111,7 +111,7 @@ _mm.defvjp(_mm_fwd, _mm_bwd)
 def matmul_tiled(x: jax.Array, w: jax.Array, *,
                  block_m: int = DEFAULT_BLOCK_M,
                  block_n: int = DEFAULT_BLOCK_N,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """``[M, K] @ [K, N] -> [M, N]`` Pallas tiled matmul, fp32
     accumulation.  The grid tiles M x N; each grid step holds a
     ``(block_m, K)`` x ``(K, block_n)`` operand pair in VMEM."""
@@ -121,14 +121,18 @@ def matmul_tiled(x: jax.Array, w: jax.Array, *,
 def fused_matmul_rs(x: jax.Array, w: jax.Array, axis, *,
                     block_m: int = DEFAULT_BLOCK_M,
                     block_n: int = DEFAULT_BLOCK_N,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """Fused ``reduce_scatter(x @ w)`` over ``axis`` (a mesh axis name
     or a row-major-folded tuple), run inside shard_map.
 
     ``x``: local ``[M, K_loc]``; ``w``: local ``[K_loc, N]``; returns
     ``[M/P, N]`` with device ``i`` holding row block ``i`` of the
     summed product (``lax.psum_scatter(..., tiled=True)`` semantics).
-    M must be divisible by the folded axis size."""
+    M must be divisible by the folded axis size.  ``interpret=None``
+    takes the backend's mode (``ops.default_interpret``)."""
+    if interpret is None:
+        from repro.kernels.ops import default_interpret
+        interpret = default_interpret()
     p = impl._axis_size(axis)
     if p == 1:
         return matmul_tiled(x, w, block_m=block_m, block_n=block_n,

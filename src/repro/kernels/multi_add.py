@@ -31,7 +31,7 @@ def _multi_add_kernel(x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def multi_add(stacked: jax.Array, *, block_n: int = DEFAULT_BLOCK_N,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """Sum K stacked partials: [K, N] -> [N] with fp32 accumulation."""
     k, n = stacked.shape
     block_n = min(block_n, n)

@@ -69,7 +69,7 @@ def selective_scan(dt: jax.Array, x: jax.Array, b: jax.Array,
                    c: jax.Array, a: jax.Array, h0: jax.Array, *,
                    block_d: int = DEFAULT_BLOCK_D,
                    chunk: int = DEFAULT_CHUNK,
-                   interpret: bool = True):
+                   interpret: bool):
     """dt/x: [B, S, D]; b/c: [B, S, N]; a: [D, N]; h0: [B, D, N].
     Returns (y [B, S, D] fp32-accurate in x.dtype, h_final [B, D, N])."""
     bsz, s, d = x.shape
@@ -301,8 +301,7 @@ def _bwd_call(dt, x, b, c, a, hck, dy, dh_final, block_d, chunk,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
-def selective_scan_trainable(dt, x, b, c, a, h0, block_d=DEFAULT_BLOCK_D,
-                             chunk=DEFAULT_CHUNK, interpret=True):
+def selective_scan_trainable(dt, x, b, c, a, h0, block_d, chunk, interpret):
     """Differentiable fused scan: forward saves only chunk-boundary
     states; backward recomputes within chunks (flash-style)."""
     y, h_final, _ = _fwd_with_ckpt(dt, x, b, c, a, h0, block_d, chunk,

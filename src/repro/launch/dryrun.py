@@ -7,7 +7,7 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
 The two lines above MUST run before any jax import (jax locks the device
 count at first init).  Each cell:
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=...).lower(**input_specs)
         compiled = lowered.compile()
         compiled.memory_analysis()   # proves it fits
@@ -179,7 +179,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str = "pod",
     record["n_devices"] = int(mesh.devices.size)
     policy = rules.for_mesh(mesh, fsdp=fsdp)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         # ---- (1) full-config compile (scan): the dry-run proof ----
         t0 = time.time()
         lowered = _lower_cell(cfg, shape, mesh, policy, microbatches,

@@ -9,19 +9,50 @@ The compiled module is the post-SPMD per-device program, so
 *per-device* quantities; dividing by per-chip peaks gives seconds
 directly (equivalent to the global/(chips*peak) form in the task spec).
 
-Hardware constants: TPU v5e -- 197 bf16 TFLOP/s, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware constants come from ``DEVICE_PEAKS``, one table keyed by
+``device.device_kind``; the dry-run models a TPU v5e pod, so its terms
+use the v5e row.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict
+from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
-HBM_BW = 819e9             # bytes/s per chip
-LINK_BW = 50e9             # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops: float        # bf16 FLOP/s per chip
+    hbm_bw: float       # HBM bytes/s per chip
+    link_bw: float      # bytes/s per ICI link
+    source: str
+
+
+#: Published per-chip peaks by ``device_kind``.  A device that is not
+#: listed has no peaks: ``device_peaks`` raises rather than guess.
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+               "bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip "
+               "over 4 links"),
+}
+
+V5E = DEVICE_PEAKS["TPU v5 lite"]
+
+
+def device_peaks(device_kind: Optional[str] = None) -> DevicePeaks:
+    """Peaks of ``device_kind`` (default: the first local device's)."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise LookupError(
+            f"no published peaks for device kind {device_kind!r}; add "
+            f"them to DEVICE_PEAKS with their source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -118,7 +149,7 @@ class RooflineTerms:
         if the dominant term were perfectly overlapped with the rest)."""
         if self.total_s <= 0:
             return float("nan")
-        useful_s = self.model_flops_global / (self.n_devices * PEAK_FLOPS)
+        useful_s = self.model_flops_global / (self.n_devices * V5E.flops)
         return useful_s / self.total_s
 
     def as_dict(self) -> dict:
@@ -138,9 +169,9 @@ def roofline_terms(flops_per_device: float, hbm_bytes_per_device: float,
                    collective_bytes_per_device: float, n_devices: int,
                    model_flops_global: float) -> RooflineTerms:
     return RooflineTerms(
-        compute_s=flops_per_device / PEAK_FLOPS,
-        memory_s=hbm_bytes_per_device / HBM_BW,
-        collective_s=collective_bytes_per_device / LINK_BW,
+        compute_s=flops_per_device / V5E.flops,
+        memory_s=hbm_bytes_per_device / V5E.hbm_bw,
+        collective_s=collective_bytes_per_device / V5E.link_bw,
         model_flops_global=model_flops_global,
         hlo_flops_global=flops_per_device * n_devices,
         n_devices=n_devices,
@@ -161,7 +192,7 @@ def model_flops(cfg, shape) -> float:
 
 
 __all__ = [
-    "PEAK_FLOPS", "HBM_BW", "LINK_BW", "COLLECTIVE_OPS",
+    "DEVICE_PEAKS", "DevicePeaks", "device_peaks", "V5E", "COLLECTIVE_OPS",
     "parse_collective_bytes", "collective_total", "RooflineTerms",
     "roofline_terms", "model_flops",
 ]

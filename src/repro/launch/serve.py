@@ -1,6 +1,7 @@
 """Serving CLI: a thin driver over ``repro.serving`` (paged KV cache +
-true continuous batching; CPU-scale here, the dry-run proves the
-production shapes).
+true continuous batching).  By default it serves the architecture at
+its published widths (minicpm-2b fits one 16 GB TPU v5e chip in bf16);
+``--reduced`` swaps in the tiny same-family config for CPU runs.
 
 Requests arrive with different prompts and lengths; the scheduler
 admits and retires them *every decode step* -- a short request frees
@@ -30,6 +31,8 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.models.frontend import vision_patches
 from repro.obs import cli as obs_cli
@@ -38,9 +41,12 @@ from repro.serving import (ContinuousBatchingServer as BatchedServer,
 from repro.serving.telemetry import ttft_low_confidence
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minicpm-2b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny same-family config (CPU runs); default "
+                         "is the published widths")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -86,22 +92,25 @@ def main():
     ap.add_argument("--arrival-rate", type=float, default=2.0,
                     help="mean arrivals per wave in the calm state")
     obs_cli.add_obs_args(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     obs_cli.begin(args.trace, args.obs_report, args.metrics_out)
 
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     from repro.models import supports_paged
     if not supports_paged(cfg):
         ap.error(
             f"--arch {args.arch} (family {cfg.family!r}) is not servable "
             f"yet: the paged KV cache covers dense/moe decoder families "
             f"(constant-state families keep the dense training cache)")
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    print(f"[serve] compile cache: {enable_compile_cache()}")
+    params = build_params(cfg)
     mesh = None
     batch = args.batch
     if args.dp:
         ndev = len(jax.devices())
-        mesh = jax.make_mesh((ndev,), ("data",))
+        mesh = make_mesh((ndev,), ("data",))
         if batch % ndev:
             batch += -batch % ndev
             print(f"[serve] rounding batch to {batch} "
@@ -146,6 +155,9 @@ def main():
                                prefill_chunk=args.prefill_chunk,
                                top_k=args.top_k,
                                prefix_cache=args.prefix_cache)
+        print(f"[serve] {describe_sizes(cfg, server)}")
+        print(f"[serve] decode program: "
+              f"{describe_memory(server.compile_decode())}")
         t0 = time.time()
         for rid in range(args.requests):
             server.submit(make_request(rid)[1])
@@ -174,13 +186,45 @@ def main():
         print(f"  req {rid}: {results[rid][:8]}...")
     tel_snap = snap if not fleet_mode else None
     if mesh is not None:
-        with mesh:
+        with jax.set_mesh(mesh):
             obs_cli.finish(args.trace, args.obs_report, args.metrics_out,
                            mesh=mesh, telemetry_snapshot=tel_snap,
                            label="serve")
     else:
         obs_cli.finish(args.trace, args.obs_report, args.metrics_out,
                        telemetry_snapshot=tel_snap, label="serve")
+
+
+def build_params(cfg, seed: int = 0):
+    """Random weights made on the device in one jitted program (an eager
+    per-layer build would hold a second copy of the layer weights)."""
+    return jax.jit(init_params, static_argnums=1)(
+        jax.random.PRNGKey(seed), cfg)
+
+
+def _gib(nbytes: float) -> str:
+    return f"{nbytes / 2**30:.2f} GiB"
+
+
+def describe_sizes(cfg, server) -> str:
+    """Parameter and KV-pool bytes of a built server."""
+    nbytes = lambda tree: sum(x.nbytes for x in jax.tree.leaves(tree))
+    return (f"{cfg.name}: {cfg.num_layers} layers, d_model {cfg.d_model}, "
+            f"heads {cfg.num_heads}/{cfg.num_kv_heads}, vocab "
+            f"{cfg.vocab_size} | params {_gib(nbytes(server.params))} | "
+            f"kv pool {server.allocator.num_blocks} blocks x "
+            f"{server.block_size} = {_gib(nbytes(server.pages))}")
+
+
+def describe_memory(compiled) -> str:
+    """The compiler's per-device memory analysis of a compiled program."""
+    ma = compiled.memory_analysis()
+    if ma is None:
+        return "memory analysis not reported by this backend"
+    return (f"arguments {_gib(ma.argument_size_in_bytes)}, outputs "
+            f"{_gib(ma.output_size_in_bytes)}, aliased "
+            f"{_gib(ma.alias_size_in_bytes)}, temporaries "
+            f"{_gib(ma.temp_size_in_bytes)}")
 
 
 def _run_fleet(args, cfg, params, batch, max_len, mesh, make_request):

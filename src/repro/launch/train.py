@@ -23,6 +23,8 @@ from repro.checkpoint import CheckpointManager
 from repro.obs import cli as obs_cli
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, SyntheticLMDataset
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.models.frontend import audio_frames, vision_patches
 from repro.optim.adamw import AdamWConfig
@@ -37,7 +39,7 @@ def make_dp_mesh():
     the device count splits evenly, a single pod otherwise."""
     nd = len(jax.devices())
     pod = 2 if nd >= 4 and nd % 2 == 0 else 1
-    return jax.make_mesh((pod, nd // pod), ("pod", "data"))
+    return make_mesh((pod, nd // pod), ("pod", "data"))
 
 
 def build_batch(cfg, data_batch, key):
@@ -160,7 +162,7 @@ def run(arch: str, steps: int, batch_size: int, seq_len: int,
             batch = {k: jax.device_put(v, batch_sharding)
                      for k, v in batch.items()}
         if mesh is not None:
-            with mesh:
+            with jax.set_mesh(mesh):
                 state, metrics = step_fn(state, batch)
         else:
             state, metrics = step_fn(state, batch)
@@ -188,7 +190,7 @@ def run(arch: str, steps: int, batch_size: int, seq_len: int,
           f"{time.time() - t_start:.1f}s; "
           f"loss {losses[0]:.4f} -> {losses[-1]:.4f}")
     if mesh is not None:
-        with mesh:
+        with jax.set_mesh(mesh):
             obs_cli.finish(trace, obs_report, metrics_out, mesh=mesh,
                            label="train")
     else:
@@ -237,6 +239,7 @@ def main():
                          "8-virtual-device EP world under --reduced)")
     obs_cli.add_obs_args(ap)
     args = ap.parse_args()
+    print(f"[train] compile cache: {enable_compile_cache()}")
     run(args.arch, args.steps, args.batch, args.seq, reduced=args.reduced,
         ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every, lr=args.lr,
         microbatches=args.microbatches, dp=args.dp,

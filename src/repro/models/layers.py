@@ -26,24 +26,18 @@ _KV_CHUNK = 1024
 # Without these, GSPMD is free to pick pathological strategies (e.g.
 # partial-summing attention logits over a split head_dim, or reducing
 # activations over the FSDP axis instead of gathering weights).  The
-# hints use the ambient mesh when one is active (dry-run, launchers) and
-# are no-ops otherwise (CPU unit tests).
+# hints use the mesh installed with ``jax.set_mesh`` (dry-run,
+# launchers) and are no-ops otherwise (CPU unit tests).
 # ---------------------------------------------------------------------- #
 def _ambient_mesh():
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        pass
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty and mesh.axis_names:
-            return mesh
-    except Exception:
-        pass
-    return None
+    """The mesh set by ``jax.set_mesh`` when every axis is Auto (GSPMD
+    partitions it), else None: no mesh is set, or the caller runs inside
+    a shard_map body that already owns some axes."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or any(t != jax.sharding.AxisType.Auto
+                         for t in mesh.axis_types):
+        return None
+    return mesh
 
 
 def shard_hint(x: jax.Array, *dims: str | None) -> jax.Array:
@@ -251,13 +245,13 @@ def flash_decode(q: jax.Array, ck: jax.Array, cv: jax.Array,
                  kv_len: jax.Array, mesh, dp_spec) -> jax.Array:
     """q: [B, 1, H, D] (replicated over model); ck/cv: [B, S, KV, D]
     sequence-sharded over 'model'.  Returns [B, 1, H, D]."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, _, h, d = q.shape
     kvh = ck.shape[2]
     group = h // kvh
-    n_ranks = dict(zip(mesh.axis_names, mesh.devices.shape))["model"]
+    n_ranks = dict(mesh.shape)["model"]
     s_local = ck.shape[1] // n_ranks
 
     def body(q_l, k_l, v_l, kv_len_l):
@@ -291,7 +285,7 @@ def flash_decode(q: jax.Array, ck: jax.Array, cv: jax.Array,
                   P(dp_spec, "model", None, None),
                   P()),
         out_specs=P(dp_spec, None, None, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, ck, cv, jnp.asarray(kv_len, jnp.int32))
 
 
@@ -300,7 +294,7 @@ def use_flash_decode(b: int, sq: int, skv: int, kvh: int):
     mesh = _ambient_mesh()
     if mesh is None or sq != 1 or "model" not in mesh.axis_names:
         return None
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = dict(mesh.shape)
     m = sizes["model"]
     if kvh % m == 0 or skv % m != 0:
         return None   # head-sharded caches take the regular path
@@ -369,7 +363,7 @@ def fused_tp_enabled() -> bool:
 
 
 def _fused_tp_applicable(mesh, h: jax.Array) -> bool:
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = dict(mesh.shape)
     pm = sizes.get("model", 1)
     if pm <= 1:
         return False
@@ -387,7 +381,7 @@ def _fused_tp_applicable(mesh, h: jax.Array) -> bool:
 def _fused_down_proj(h: jax.Array, w_down: jax.Array, mesh) -> jax.Array:
     """``psum(h @ w_down)`` over 'model' as reduce-scatter + allgather,
     the RS fused with the GEMM ring."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.collectives.api import get_engine
 
@@ -407,7 +401,7 @@ def _fused_down_proj(h: jax.Array, w_down: jax.Array, mesh) -> jax.Array:
 
     fn = shard_map(body, mesh=mesh,
                    in_specs=(P(dp_spec, None, "model"), P("model", None)),
-                   out_specs=P(dp_spec, None, None), check_rep=False)
+                   out_specs=P(dp_spec, None, None), check_vma=False)
     return fn(h, w_down)
 
 

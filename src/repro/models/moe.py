@@ -185,7 +185,7 @@ def moe_ffn_sharded(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     if mesh is None or "model" not in mesh.axis_names:
         return moe_ffn(x, router_w, w_gate, w_up, w_down, top_k=top_k,
                        capacity_factor=capacity_factor)
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     import functools
 
@@ -194,7 +194,7 @@ def moe_ffn_sharded(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     dp_spec = dp if len(dp) > 1 else (dp[0] if dp else None)
     fsdp_axis = "data" if "data" in names else None
     e_total = router_w.shape[1]
-    model_size = dict(zip(mesh.axis_names, mesh.devices.shape))["model"]
+    model_size = dict(mesh.shape)["model"]
     if (e_total % model_size != 0
             or (dp and x.shape[0] % mesh.shape[dp[0]] != 0)):
         return moe_ffn(x, router_w, w_gate, w_up, w_down, top_k=top_k,
@@ -211,7 +211,7 @@ def moe_ffn_sharded(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                   P("model", fsdp_axis, None),       # w_up
                   P("model", None, fsdp_axis)),      # w_down
         out_specs=(P(dp_spec, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     return fn(x, router_w, w_gate, w_up, w_down)
 
 

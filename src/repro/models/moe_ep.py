@@ -73,7 +73,7 @@ def _ep_axes_for(mesh) -> Tuple[str, ...]:
     targets).  Size-1 axes are skipped so a trivial model axis does
     not shadow a usable expert mesh."""
     names = mesh.axis_names
-    sizes = dict(zip(names, mesh.devices.shape))
+    sizes = dict(mesh.shape)
     if sizes.get("model", 0) > 1:
         return ("model",)
     return tuple(a for a in ("pod", "data") if sizes.get(a, 0) > 1)
@@ -173,7 +173,7 @@ def moe_ffn_ep(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     back to ``moe_ffn_sharded`` (and transitively the GSPMD path) when
     there is no mesh or the shapes don't tile the EP world."""
     from repro.models.layers import _ambient_mesh
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _ambient_mesh()
@@ -183,7 +183,7 @@ def moe_ffn_ep(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                          router_w, w_gate, w_up, w_down, top_k=top_k,
                          capacity_factor=capacity_factor)
     names = mesh.axis_names
-    sizes = dict(zip(names, mesh.devices.shape))
+    sizes = dict(mesh.shape)
     dp_axes = tuple(a for a in ("pod", "data")
                     if a in names and a not in ep_axes)
     token_axes = dp_axes + ep_axes
@@ -221,7 +221,7 @@ def moe_ffn_ep(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                   P(ep_spec, fsdp_axis, None),       # w_up
                   P(ep_spec, None, fsdp_axis)),      # w_down
         out_specs=(P(tok_spec, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     return fn(x, router_w, w_gate, w_up, w_down)
 
 

@@ -5,7 +5,10 @@ transformer.py).
 The physical cache is a pool of fixed-size blocks shared by every
 request in the batch::
 
-    pages = {"k": [L, N, bs, Hkv, Hd], "v": [L, N, bs, Hkv, Hd]}
+    pages = {"k": [L, N, bs, Hkv * Hd], "v": [L, N, bs, Hkv * Hd]}
+
+(heads merged into the minor dim: a block is one dense ``(bs, Hkv *
+Hd)`` tile in HBM, which is what the decode kernel DMAs per step).
 
 Per-request state lives host-side in the serving scheduler and is passed
 in per call: ``block_tables`` [B, M] int32 (pool indices in logical
@@ -35,9 +38,11 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.models.layers import apply_rope, rms_norm, shard_hint
+from repro.models.layers import (_ambient_mesh, apply_rope, rms_norm,
+                                 shard_hint)
 from repro.models.transformer import _ffn_sublayer, _unembed
 
 Params = Dict[str, Any]
@@ -57,8 +62,8 @@ def init_pages(cfg: ArchConfig, num_blocks: int,
             f"paged KV cache supports families {PAGED_FAMILIES}, "
             f"not {cfg.family!r} (constant-state families keep the dense "
             f"cache)")
-    shape = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads,
-             cfg.resolved_head_dim)
+    shape = (cfg.num_layers, num_blocks, block_size,
+             cfg.num_kv_heads * cfg.resolved_head_dim)
     dtype = cfg.activation_dtype
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -80,7 +85,8 @@ def copy_blocks(pages: Dict[str, jax.Array], src: jax.Array,
 def _write_pages(pages_l: jax.Array, new: jax.Array,
                  block_tables: jax.Array, ctx_lens: jax.Array,
                  valid: jax.Array) -> jax.Array:
-    """Scatter [B, T, Hkv, Hd] new KV into one layer's pool.
+    """Scatter [B, T, Hkv, Hd] new KV into one layer's [N, bs, Hkv * Hd]
+    pool.
 
     Position ctx+t of row b lands in slot (ctx+t) % bs of block
     block_tables[b, (ctx+t) // bs]; invalid positions (padded tails,
@@ -94,9 +100,9 @@ def _write_pages(pages_l: jax.Array, new: jax.Array,
                               jnp.minimum(pos // bs, m - 1), axis=1)
     flat = blk * bs + pos % bs
     flat = jnp.where(valid, flat, pos % bs)                   # scratch
-    out = pages_l.reshape(n * bs, *pages_l.shape[2:])
+    out = pages_l.reshape(n * bs, pages_l.shape[2])
     out = out.at[flat.reshape(-1)].set(
-        new.reshape(b * t, *new.shape[2:]).astype(pages_l.dtype))
+        new.reshape(b * t, -1).astype(pages_l.dtype))
     return out.reshape(pages_l.shape)
 
 
@@ -106,17 +112,20 @@ def _gathered_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
     """Pure-jnp paged attention for T >= 1 (prefill chunks, CPU decode).
 
     q: [B, T, H, Hd] at absolute positions ctx..ctx+T-1; kp/vp:
-    [N, bs, Hkv, Hd] pool *after* the chunk's writes.  Causal over the
+    [N, bs, Hkv * Hd] pool *after* the chunk's writes.  Causal over the
     gathered logical context.
     """
-    n, bs, hkv, hd = kp.shape
-    b, t, h, _ = q.shape
+    n, bs, _ = kp.shape
+    b, t, h, hd = q.shape
+    hkv = kp.shape[2] // hd
     m = block_tables.shape[1]
     group = h // hkv
     idx = (block_tables[:, :, None] * bs
            + jnp.arange(bs)[None, None, :]).reshape(b, m * bs)
-    k = kp.reshape(n * bs, hkv, hd)[idx]                      # [B, S, Hkv, Hd]
-    v = vp.reshape(n * bs, hkv, hd)[idx]
+    # gather the referenced rows, then split heads on the gathered
+    # context only (splitting the pool's minor dim would relayout it)
+    k = kp.reshape(n * bs, -1)[idx].reshape(b, m * bs, hkv, hd)
+    v = vp.reshape(n * bs, -1)[idx].reshape(b, m * bs, hkv, hd)
     kt = jnp.repeat(jnp.moveaxis(k, 1, 2), group, axis=1)     # [B, H, S, Hd]
     vt = jnp.repeat(jnp.moveaxis(v, 1, 2), group, axis=1)
     qt = jnp.moveaxis(q, 1, 2)                                # [B, H, T, Hd]
@@ -129,6 +138,25 @@ def _gathered_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", probs, vt.astype(jnp.float32))
     return jnp.moveaxis(out.astype(q.dtype), 1, 2)            # [B, T, H, Hd]
+
+
+def _kernel_attention(q: jax.Array, kp: jax.Array, vp: jax.Array,
+                      block_tables: jax.Array, lengths: jax.Array
+                      ) -> jax.Array:
+    """The Pallas decode kernel.  A Mosaic kernel cannot be partitioned
+    by GSPMD, so under a mesh with batch axes it runs per batch shard
+    (rows split, pool replicated)."""
+    from repro.kernels.ops import paged_attention
+    mesh = _ambient_mesh()
+    dp = tuple(a for a in ("pod", "data")
+               if mesh is not None and a in mesh.axis_names)
+    if not dp:
+        return paged_attention(q, kp, vp, block_tables, lengths)
+    rows = P(dp if len(dp) > 1 else dp[0])
+    return jax.shard_map(paged_attention, mesh=mesh,
+                         in_specs=(rows, P(), P(), rows, rows),
+                         out_specs=rows, check_vma=False)(
+        q, kp, vp, block_tables, lengths)
 
 
 def _paged_decoder_block(cfg: ArchConfig, x, lp, kp, vp, block_tables,
@@ -148,9 +176,8 @@ def _paged_decoder_block(cfg: ArchConfig, x, lp, kp, vp, block_tables,
     kp = _write_pages(kp, k, block_tables, ctx_lens, valid)
     vp = _write_pages(vp, v, block_tables, ctx_lens, valid)
     if t == 1 and use_kernel:
-        from repro.kernels.ops import paged_attention
-        out = paged_attention(q[:, 0], kp, vp, block_tables,
-                              ctx_lens + 1)[:, None]
+        out = _kernel_attention(q[:, 0], kp, vp, block_tables,
+                                ctx_lens + 1)[:, None]
     else:
         out = _gathered_attention(q, kp, vp, block_tables, ctx_lens)
     x = x + shard_hint(out.reshape(b, t, h * hd) @ lp["wo"],

@@ -90,13 +90,13 @@ def mamba_block(x: jax.Array, p: dict, *, ssm_state: int, chunk: int = 128,
         # fused Pallas scan: the state expansion never touches HBM
         # (repro/kernels/selective_scan.py; EXPERIMENTS.md §Perf Cell C)
         import math as _math
+        from repro.kernels.ops import default_interpret
         from repro.kernels.selective_scan import selective_scan_trainable
         bd = _math.gcd(di, 256)
         ck = _math.gcd(s, 128)
         y, h_new = selective_scan_trainable(
             delta32, xs.astype(jnp.float32), b_ssm.astype(jnp.float32),
-            c_ssm.astype(jnp.float32), a, h0, bd, ck,
-            jax.default_backend() != "tpu")
+            c_ssm.astype(jnp.float32), a, h0, bd, ck, default_interpret())
     else:
         h_all, h_new = chunked_linear_scan(a_bar, b_bar, h0, chunk=chunk)
         h_all = shard_hint(h_all, "dp", None, "model", None)

@@ -94,6 +94,13 @@ def _stack(layer_params):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *layer_params)
 
 
+def _stacked(make_layer, keys):
+    """``_stack([make_layer(k) for k in keys])`` built in one vmapped
+    pass: the same values, without a per-layer copy of every weight
+    alive next to the stacked result."""
+    return jax.vmap(make_layer)(keys)
+
+
 def init_params(key, cfg: ArchConfig) -> Params:
     dtype = cfg.activation_dtype
     d, v = cfg.d_model, cfg.vocab_size
@@ -107,13 +114,13 @@ def init_params(key, cfg: ArchConfig) -> Params:
     lkeys = jax.random.split(k_layers, max(cfg.num_layers, 1) + 8)
 
     if cfg.family == "ssm":
-        layers = [
-            {"ln": _norm(d, dtype),
-             **ssm_lib.init_mamba_params(lkeys[i], d, cfg.d_inner,
-                                         cfg.ssm_state, cfg.dt_rank,
-                                         cfg.ssm_conv, dtype)}
-            for i in range(cfg.num_layers)]
-        params["layers"] = _stack(layers)
+        params["layers"] = _stacked(
+            lambda k: {"ln": _norm(d, dtype),
+                       **ssm_lib.init_mamba_params(k, d, cfg.d_inner,
+                                                   cfg.ssm_state,
+                                                   cfg.dt_rank,
+                                                   cfg.ssm_conv, dtype)},
+            lkeys[:cfg.num_layers])
         return params
 
     if cfg.family == "hybrid":
@@ -163,9 +170,8 @@ def init_params(key, cfg: ArchConfig) -> Params:
         return params
 
     # dense / moe / vlm decoder stacks
-    layers = [_decoder_layer(lkeys[i], cfg, dtype)
-              for i in range(cfg.num_layers)]
-    params["layers"] = _stack(layers)
+    params["layers"] = _stacked(lambda k: _decoder_layer(k, cfg, dtype),
+                                lkeys[:cfg.num_layers])
     return params
 
 

@@ -24,7 +24,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.obs import trace as obs_trace
 
@@ -74,7 +74,7 @@ def _build_call(engine, mesh: Mesh, sig: Signature):
         else:
             fn = lambda v: engine.allreduce_inside(v, axes[0], algo)
         wrapped = shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
-                            check_rep=False)
+                            check_vma=False)
     elif op == "reduce_scatter":
         m = max(1, nbytes // (4 * p))
         x = jnp.zeros((p * m,), jnp.float32)
@@ -83,7 +83,7 @@ def _build_call(engine, mesh: Mesh, sig: Signature):
         else:
             fn = lambda v: engine.reduce_scatter_inside(v, axes[0], algo)
         wrapped = shard_map(fn, mesh=mesh, in_specs=P(), out_specs=spec,
-                            check_rep=False)
+                            check_vma=False)
     elif op == "allgather":
         # span nbytes is the *global* gathered size (the model's B)
         n = max(1, nbytes // 4)
@@ -94,7 +94,7 @@ def _build_call(engine, mesh: Mesh, sig: Signature):
         else:
             fn = lambda v: engine.allgather_inside(v, axes[0], algo)
         wrapped = shard_map(fn, mesh=mesh, in_specs=spec, out_specs=P(),
-                            check_rep=False)
+                            check_vma=False)
     elif op == "all_to_all":
         # span nbytes is the per-device shard: [p * m] rows locally
         m = max(1, nbytes // (4 * p))
@@ -104,7 +104,7 @@ def _build_call(engine, mesh: Mesh, sig: Signature):
         else:
             fn = lambda v: engine.all_to_all_inside(v, axes[0], algo)
         wrapped = shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                            check_rep=False)
+                            check_vma=False)
     else:
         return None
     jitted = jax.jit(wrapped)

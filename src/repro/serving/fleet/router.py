@@ -36,7 +36,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence
 
-from repro.launch.roofline import PEAK_FLOPS
+from repro.launch.roofline import device_peaks
 from repro.serving.fleet.replica import LoadSignal, Replica
 from repro.serving.scheduler import Request
 
@@ -86,8 +86,9 @@ class CostRouter(Router):
         cost(r) = p_tok * (uncached_suffix_tokens(req, r)
                            + pending_prefill_tokens(r))
 
-    with ``p_tok = 2 * active_params / PEAK_FLOPS`` (the roofline
-    inference-compute price per token).  The uncached suffix is
+    with ``p_tok = 2 * active_params / peak_flops`` (the roofline
+    inference-compute price per token); ``peak_flops`` defaults to the
+    published peak of the device serving (``launch.roofline``).  The uncached suffix is
     predicted from the replica's prefix cache by probing the request's
     hash-chain keys -- the same content addressing admission will use,
     so the prediction only errs when blocks are evicted in between.
@@ -95,8 +96,10 @@ class CostRouter(Router):
 
     name = "cost"
 
-    def __init__(self, cfg):
-        self.price_per_token_s = 2.0 * cfg.active_param_count() / PEAK_FLOPS
+    def __init__(self, cfg, peak_flops: Optional[float] = None):
+        if peak_flops is None:
+            peak_flops = device_peaks().flops
+        self.price_per_token_s = 2.0 * cfg.active_param_count() / peak_flops
         #: modeled cost of each routed request (seconds), for telemetry
         self.last_costs: List[float] = []
 
@@ -156,8 +159,10 @@ class PrefixAffinityRouter(Router):
 ROUTER_POLICIES = ("round_robin", "least_queue", "cost", "prefix_affinity")
 
 
-def make_router(policy: str, cfg=None) -> Router:
-    """Build a router by policy name (``cfg`` required for ``cost``)."""
+def make_router(policy: str, cfg=None,
+                peak_flops: Optional[float] = None) -> Router:
+    """Build a router by policy name (``cfg`` required for ``cost``;
+    ``peak_flops`` as for :class:`CostRouter`)."""
     if policy == "round_robin":
         return RoundRobinRouter()
     if policy == "least_queue":
@@ -166,7 +171,7 @@ def make_router(policy: str, cfg=None) -> Router:
         if cfg is None:
             raise ValueError("cost router needs the model config to "
                              "price prefill compute")
-        return CostRouter(cfg)
+        return CostRouter(cfg, peak_flops)
     if policy == "prefix_affinity":
         return PrefixAffinityRouter()
     raise ValueError(f"unknown router policy {policy!r}; "

@@ -23,6 +23,7 @@ identical tokens.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Dict, List, Optional
 
@@ -31,7 +32,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.collectives.api import get_engine
 from repro.models.paged import (copy_blocks, decode_step_paged,
@@ -109,9 +110,12 @@ class ContinuousBatchingServer:
                     f"{mesh.shape[dp_axis]}")
             self._engine = engine or get_engine()
             self._row_sharding = NamedSharding(mesh, P(dp_axis))
-            # replicate the block pool across the DP shards up front so
-            # every program runs on the mesh from the first call
-            self.pages = jax.device_put(self.pages, NamedSharding(mesh, P()))
+            # replicate the weights and the block pool across the DP
+            # shards up front so every program runs on the mesh from the
+            # first call (and no call re-sends them)
+            replicated = NamedSharding(mesh, P())
+            self.params = jax.device_put(self.params, replicated)
+            self.pages = jax.device_put(self.pages, replicated)
 
         key = self.key
 
@@ -175,15 +179,35 @@ class ContinuousBatchingServer:
                 _gathered(lambda lg, sid, tmp, tk:
                           sample_tokens(lg, sid, tmp, key, tk)),
                 mesh=mesh, in_specs=row_specs, out_specs=P(),
-                check_rep=False))
+                check_vma=False))
             self._sample_notopk_gather_fn = jax.jit(shard_map(
                 _gathered(lambda lg, sid, tmp:
                           sample_tokens(lg, sid, tmp, key, 0)),
                 mesh=mesh, in_specs=row_specs[:3], out_specs=P(),
-                check_rep=False))
+                check_vma=False))
             self._greedy_gather_fn = jax.jit(shard_map(
                 _gathered(greedy), mesh=mesh, in_specs=P(dp_axis),
-                out_specs=P(), check_rep=False))
+                out_specs=P(), check_vma=False))
+
+    def compile_decode(self):
+        """Ahead-of-time compile of the decode program at this server's
+        shapes (the program every decode step runs), for
+        ``memory_analysis()`` and ``as_text()``."""
+        sharding = self._row_sharding if self.mesh is not None else None
+        rows = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                                   sharding=sharding)
+        b, m = self.batch, self.max_blocks_per_seq
+        with self._on_mesh():
+            return self._decode_fn.lower(self.params, self.pages,
+                                         rows(b, 1), rows(b, m),
+                                         rows(b)).compile()
+
+    def _on_mesh(self):
+        """Trace and run the decode program with the DP mesh set, so the
+        model's sharding hints and the per-shard decode kernel see it."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
 
     # ------------------------------------------------------------------ #
     def submit(self, req: Request) -> None:
@@ -266,10 +290,11 @@ class ContinuousBatchingServer:
                                     gathered=False)
             self._append_token(req, int(tok[0]))
 
-    def _run_decode(self) -> None:
+    def decode_batch(self):
+        """The next decode step's inputs: (running [(row, request)],
+        [tokens [B, 1], block tables [B, M], context lengths [B]]),
+        idle rows zero (their writes land in the scratch block)."""
         running = self.scheduler.running()
-        rows = [i for i, _ in running]
-        reqs = [r for _, r in running]
         tokens = np.zeros((self.batch, 1), np.int32)
         bt = np.zeros((self.batch, self.max_blocks_per_seq), np.int32)
         ctx = np.zeros((self.batch,), np.int32)
@@ -280,7 +305,15 @@ class ContinuousBatchingServer:
         args = [jnp.asarray(tokens), jnp.asarray(bt), jnp.asarray(ctx)]
         if self.mesh is not None:
             args = [jax.device_put(a, self._row_sharding) for a in args]
-        logits, self.pages = self._decode_fn(self.params, self.pages, *args)
+        return running, args
+
+    def _run_decode(self) -> None:
+        running, args = self.decode_batch()
+        rows = [i for i, _ in running]
+        reqs = [r for _, r in running]
+        with self._on_mesh():
+            logits, self.pages = self._decode_fn(self.params, self.pages,
+                                                 *args)
         toks = self._sample_rows(logits[:, 0], reqs, rows,
                                  gathered=self.mesh is not None)
         for i, req in running:

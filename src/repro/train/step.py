@@ -116,7 +116,7 @@ def fsdp_sync_apply(opt_cfg: AdamWConfig, params, grads, opt,
     place.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.collectives.api import get_engine
     from repro.collectives.overlap import flatten_tree, unflatten_tree
     from repro.optim.adamw import lr_at
@@ -196,7 +196,7 @@ def fsdp_sync_apply(opt_cfg: AdamWConfig, params, grads, opt,
     fn = shard_map(shard_fn, mesh=gs.mesh,
                    in_specs=(P(), spec, spec, spec, spec),
                    out_specs=(P(), spec, spec, spec, P()),
-                   check_rep=False)
+                   check_vma=False)
     w_full, mu2, nu2, w2, gnorm = fn(flat_g, w32, decay, mu, nu)
     params2 = unflatten_tree(w_full[:n], meta)
     opt2 = AdamWState(mu=mu2, nu=nu2, count=count,

@@ -23,12 +23,13 @@ _SCRIPT = r"""
 import functools, json
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.collectives.api import allreduce_inside, select_algorithm
 from repro.collectives.overlap import bucketed_allreduce, bucket_algorithm_plan
 
 results = {}
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 96))
 vals = jax.device_put(x, NamedSharding(mesh, P("data", None)))
 expected = np.tile(np.asarray(x).sum(0), (8, 1))
@@ -36,19 +37,19 @@ expected = np.tile(np.asarray(x).sum(0), (8, 1))
 for algo in ("psum", "chain", "tree", "two_phase", "star", "ring", "autogen", "autogen_pipelined", "auto"):
     fn = shard_map(functools.partial(allreduce_inside, axis="data", algorithm=algo),
                    mesh=mesh, in_specs=P("data", None), out_specs=P("data", None),
-                   check_rep=False)
+                   check_vma=False)
     out = np.asarray(jax.jit(fn)(vals))
     results[f"allreduce_{algo}"] = bool(np.allclose(out, expected, rtol=1e-4, atol=1e-4))
 
 # 2-axis hierarchy (two-phase across pod x data)
-mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+mesh2 = make_mesh((2, 4), ("pod", "data"))
 v2 = jax.device_put(x, NamedSharding(mesh2, P(("pod", "data"), None)))
 def hier(v):
     v = allreduce_inside(v, "data", algorithm="chain")
     v = allreduce_inside(v, "pod", algorithm="chain")
     return v
 fn2 = shard_map(hier, mesh=mesh2, in_specs=P(("pod", "data"), None),
-                out_specs=P(("pod", "data"), None), check_rep=False)
+                out_specs=P(("pod", "data"), None), check_vma=False)
 out2 = np.asarray(jax.jit(fn2)(v2))
 results["hierarchical_two_phase"] = bool(np.allclose(out2, expected, rtol=1e-4, atol=1e-4))
 
@@ -70,12 +71,12 @@ from repro.collectives.api import (allreduce_multi_inside,
                                    reduce_scatter_multi_inside,
                                    allgather_multi_inside)
 
-mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
 x3 = jax.random.normal(jax.random.PRNGKey(2), (16, 6))
 
 def run3(fn, in_spec, out_spec):
     f = shard_map(fn, mesh=mesh3, in_specs=in_spec, out_specs=out_spec,
-                  check_rep=False)
+                  check_vma=False)
     return np.asarray(jax.jit(f)(x3))
 
 for axes in (("pod", "data"), ("pod", "data", "model")):
@@ -109,11 +110,11 @@ for axes in (("pod", "data"), ("pod", "data", "model")):
 
 # (2, 4) debug mesh: planner plans over ("data", "model"), odd vector
 # length exercising the hierarchical pad path
-mesh24 = jax.make_mesh((2, 4), ("data", "model"))
+mesh24 = make_mesh((2, 4), ("data", "model"))
 y = jax.random.normal(jax.random.PRNGKey(3), (13,))
 def run24(fn):
     f = shard_map(fn, mesh=mesh24, in_specs=P(), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     return np.asarray(jax.jit(f)(y))
 ref = run24(lambda v: jax.lax.psum(v, ("data", "model")))
 for shape in ("auto", "hierarchical", "2d_xy", "2d_snake", "flat"):
@@ -125,7 +126,7 @@ for shape in ("auto", "hierarchical", "2d_xy", "2d_snake", "flat"):
 
 # multi-axis bucketed allreduce: compress=True error-feedback over the
 # ("pod", "data") tuple routes each bucket through the planner
-mesh22 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh22 = make_mesh((2, 2, 2), ("pod", "data", "model"))
 reduced, ef = bucketed_allreduce(
     grads, mesh22, axes=("pod", "data"), bucket_bytes=2048,
     compress=True,
@@ -144,7 +145,7 @@ results["multi_plan_reports_shapes"] = len(mplan) > 1 and all(
 from repro.collectives.api import all_to_all_inside, all_to_all_multi_inside
 
 def a2a_check(mesh_shape, mesh_axes, axes, dtype, tag):
-    mesh_a = jax.make_mesh(mesh_shape, mesh_axes)
+    mesh_a = make_mesh(mesh_shape, mesh_axes)
     p = 1
     for a in axes:
         p *= mesh_a.shape[a]
@@ -153,8 +154,8 @@ def a2a_check(mesh_shape, mesh_axes, axes, dtype, tag):
     axis_ref = axes if len(axes) > 1 else axes[0]
     ref_fn = shard_map(
         lambda v: jax.lax.all_to_all(v, axis_ref, 0, 0, tiled=True),
-        mesh=mesh_a, in_specs=P(), out_specs=P(), check_rep=False)
-    with mesh_a:
+        mesh=mesh_a, in_specs=P(), out_specs=P(), check_vma=False)
+    with jax.set_mesh(mesh_a):
         ref = np.asarray(jax.jit(ref_fn)(xa), np.float32)
     algos = (("auto", "ring", "halving") if len(axes) == 1 else
              ("auto", "hierarchical", "sequential", "flat", "ring",
@@ -167,8 +168,8 @@ def a2a_check(mesh_shape, mesh_axes, axes, dtype, tag):
             body = functools.partial(all_to_all_multi_inside, axes=axes,
                                      algorithm=algo)
         fn = shard_map(body, mesh=mesh_a, in_specs=P(), out_specs=P(),
-                       check_rep=False)
-        with mesh_a:
+                       check_vma=False)
+        with jax.set_mesh(mesh_a):
             out = np.asarray(jax.jit(fn)(xa), np.float32)
         results[f"a2a_{tag}_{algo}"] = bool(
             np.allclose(out, ref, rtol=1e-4, atol=1e-4))

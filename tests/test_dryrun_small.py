@@ -40,7 +40,7 @@ for name in ("yi-34b", "olmoe-1b-7b", "falcon-mamba-7b",
     bsh = {k: NamedSharding(mesh, s)
            for k, s in rules.batch_sharding_specs(policy, bs).items()}
     step = make_train_step(cfg, AdamWConfig())
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step, in_shardings=(state_sh, bsh),
                            donate_argnums=(0,)).lower(state_specs, bs).compile()
     ca = compiled.cost_analysis()

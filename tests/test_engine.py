@@ -330,17 +330,18 @@ import functools, json
 import numpy as np, jax, jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.collectives.engine import CollectiveEngine
 
 results = {}
 eng = CollectiveEngine(persist=False)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 x = jax.random.normal(jax.random.PRNGKey(0), (64, 24))
 
 def run(fn, in_spec, out_spec):
     f = shard_map(fn, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
-                  check_rep=False)
+                  check_vma=False)
     return np.asarray(jax.jit(f)(x))
 
 # reduce_scatter vs lax.psum_scatter
@@ -381,18 +382,18 @@ results["allreduce_auto"] = bool(np.allclose(out, ref, rtol=1e-4,
 eng2 = CollectiveEngine(persist=False)
 g = shard_map(functools.partial(eng2.allreduce_inside, axis="data",
                                 algorithm="auto"),
-              mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+              mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
 jax.jit(g).lower(x)
 first = dict(eng2.stats)
 g2 = shard_map(functools.partial(eng2.allreduce_inside, axis="data",
                                  algorithm="auto"),
-               mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+               mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
 jax.jit(g2).lower(x)
 results["retrace_no_new_miss"] = (eng2.stats["misses"] == first["misses"])
 results["retrace_hits_cache"] = (eng2.stats["hits"] > first["hits"])
 h = shard_map(functools.partial(eng2.allreduce_inside, axis="data",
                                 algorithm="autogen"),
-              mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+              mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
 jax.jit(h).lower(x)
 dp_after_first = eng2.stats["dp_runs"]
 jax.jit(h).lower(x * 2.0)
@@ -424,7 +425,7 @@ ref_state, ref_metrics = jax.jit(make_train_step(cfg, opt))(
 sharded = {k: jax.device_put(v, NamedSharding(mesh, P("data")))
            for k, v in batch.items()}
 step = make_train_step(cfg, opt, grad_sync=GradSyncConfig(mesh=mesh))
-with mesh:
+with jax.set_mesh(mesh):
     state2, metrics = jax.jit(step)(init_train_state(params), sharded)
 results["grad_sync_finite"] = bool(np.isfinite(float(metrics["loss"])))
 ref_leaves = jax.tree.leaves(ref_state.params)
@@ -437,7 +438,7 @@ results["grad_sync_matches_gspmd"] = all(
 # params, over the hierarchical (pod, data) topology.  Loss and params
 # must track the GSPMD baseline at fp32 tolerance across steps (incl.
 # the step-0 tree->flat optimizer-state conversion).
-mesh_h = jax.make_mesh((2, 4), ("pod", "data"))
+mesh_h = make_mesh((2, 4), ("pod", "data"))
 sharded_h = {k: jax.device_put(v, NamedSharding(mesh_h, P(("pod", "data"))))
              for k, v in batch.items()}
 fsdp_step = make_train_step(cfg, opt, grad_sync=GradSyncConfig(
@@ -448,7 +449,7 @@ ref_jit = jax.jit(make_train_step(cfg, opt))
 ok_loss, ok_gnorm = True, True
 for _ in range(2):
     state_ref, m_ref = ref_jit(state_ref, batch)
-    with mesh_h:
+    with jax.set_mesh(mesh_h):
         state_f, m_f = jax.jit(fsdp_step)(state_f, sharded_h)
     ok_loss &= bool(np.allclose(float(m_ref["loss"]), float(m_f["loss"]),
                                 rtol=1e-5, atol=1e-6))
@@ -475,7 +476,7 @@ state_mf = init_train_state(params_bf, master_weights=True)
 ref_jit_m = jax.jit(make_train_step(cfg, opt))
 for _ in range(2):
     state_mref, _ = ref_jit_m(state_mref, batch)
-    with mesh_h:
+    with jax.set_mesh(mesh_h):
         state_mf, _ = jax.jit(fsdp_m_step)(state_mf, sharded_h)
 results["fsdp_master_params_match_gspmd"] = all(
     np.allclose(np.asarray(a, dtype=np.float32),

@@ -13,10 +13,11 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ArchConfig
+from repro.launch.roofline import V5E, device_peaks
 from repro.models import init_params
 from repro.serving import ContinuousBatchingServer, Request
 from repro.serving.fleet import (AdmissionConfig, AdmissionController,
-                                 FleetServer, LoadSignal,
+                                 CostRouter, FleetServer, LoadSignal,
                                  REJECT_QUEUE_FULL, REJECT_RATE_LIMITED,
                                  ROUTER_POLICIES, Replica, arrival_waves,
                                  export_fleet_stats, make_router)
@@ -36,6 +37,9 @@ def _fleet(params, n_replicas=2, **kw):
     kw.setdefault("max_len", 32)
     kw.setdefault("block_size", 4)
     kw.setdefault("prefill_chunk", 8)
+    if kw.get("router") == "cost":
+        # the CPU has no published peak: price at the v5e rate
+        kw["router"] = CostRouter(TINY, peak_flops=V5E.flops)
     return FleetServer(TINY, params, n_replicas, **kw)
 
 
@@ -80,7 +84,22 @@ def test_make_router_rejects_unknown_and_cost_needs_cfg():
         make_router("wishful_thinking")
     with pytest.raises(ValueError):
         make_router("cost")
-    assert make_router("cost", TINY).price_per_token_s > 0
+    assert make_router("cost", TINY,
+                       peak_flops=V5E.flops).price_per_token_s > 0
+
+
+def test_peak_rates_keyed_by_device_kind():
+    """The router's rate comes from the published peak of the device
+    serving; a device with no published peak is an error, not a
+    default."""
+    assert device_peaks("TPU v5 lite") is V5E
+    with pytest.raises(LookupError):
+        device_peaks("cpu")
+    with pytest.raises(LookupError):
+        make_router("cost", TINY)            # the CPU has no peak rate
+    assert math.isclose(
+        CostRouter(TINY, peak_flops=2.0).price_per_token_s,
+        TINY.active_param_count())
 
 
 def test_cost_router_prices_uncached_suffix(tiny_params):

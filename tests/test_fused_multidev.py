@@ -24,7 +24,8 @@ import functools, json
 import numpy as np, jax, jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.collectives.api import get_engine
 from repro.kernels.fused_matmul_rs import fused_matmul_rs, matmul_then_rs
 from repro.kernels.ref import fused_matmul_rs_ref
@@ -36,7 +37,7 @@ key = jax.random.PRNGKey(7)
 # ------------------------------------------------------------------ #
 # kernel vs oracle: 1D model axis, FFN-sized and MQA-sized shapes
 # ------------------------------------------------------------------ #
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 for tag, (m, k, n) in (("ffn", (64, 512, 48)), ("mqa", (16, 64, 24))):
     kx, kw, key = *jax.random.split(key, 2), key
     x = jax.random.normal(kx, (m, k), jnp.float32)
@@ -51,7 +52,7 @@ for tag, (m, k, n) in (("ffn", (64, 512, 48)), ("mqa", (16, 64, 24))):
             ("unfused", lambda xl, wl: matmul_then_rs(xl, wl, "model"))):
         fn = shard_map(body, mesh=mesh,
                        in_specs=(P(None, "model"), P("model", None)),
-                       out_specs=P("model", None), check_rep=False)
+                       out_specs=P("model", None), check_vma=False)
         out = np.asarray(jax.jit(fn)(xs, ws))
         results[f"kernel_{tag}_{name}"] = bool(
             np.allclose(out, want, rtol=1e-5, atol=1e-5))
@@ -59,7 +60,7 @@ for tag, (m, k, n) in (("ffn", (64, 512, 48)), ("mqa", (16, 64, 24))):
 # ------------------------------------------------------------------ #
 # folded (pod, data) FSDP layout
 # ------------------------------------------------------------------ #
-mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+mesh2 = make_mesh((2, 4), ("pod", "data"))
 m, k, n = 64, 512, 32
 kx, kw, key = *jax.random.split(key, 2), key
 x = jax.random.normal(kx, (m, k), jnp.float32)
@@ -70,7 +71,7 @@ want = np.asarray(x, np.float32) @ np.asarray(w, np.float32)
 fn = shard_map(lambda xl, wl: fused_matmul_rs(xl, wl, ("pod", "data")),
                mesh=mesh2,
                in_specs=(P(None, ("pod", "data")), P(("pod", "data"), None)),
-               out_specs=P(("pod", "data"), None), check_rep=False)
+               out_specs=P(("pod", "data"), None), check_vma=False)
 out = np.asarray(jax.jit(fn)(xs, ws))
 results["kernel_folded_fsdp"] = bool(
     np.allclose(out, want, rtol=1e-4, atol=1e-4))
@@ -79,7 +80,7 @@ results["kernel_folded_fsdp"] = bool(
 # engine executor: auto / forced-fused / forced-unfused agree; the
 # w=None grad-sync degenerate equals psum_scatter
 # ------------------------------------------------------------------ #
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 xs = jax.device_put(x, NamedSharding(mesh, P(None, "model")))
 ws = jax.device_put(w, NamedSharding(mesh, P("model", None)))
 for algo in ("auto", "fused", "unfused"):
@@ -87,7 +88,7 @@ for algo in ("auto", "fused", "unfused"):
         lambda xl, wl, a=algo: eng.fused_matmul_reduce_scatter(
             xl, wl, "model", algorithm=a),
         mesh=mesh, in_specs=(P(None, "model"), P("model", None)),
-        out_specs=P("model", None), check_rep=False)
+        out_specs=P("model", None), check_vma=False)
     out = np.asarray(jax.jit(fn)(xs, ws))
     results[f"engine_{algo}"] = bool(
         np.allclose(out, want, rtol=1e-4, atol=1e-4))
@@ -100,7 +101,7 @@ def degenerate(gl):
     return a, b
 fn = shard_map(degenerate, mesh=mesh, in_specs=P(None, None),
                out_specs=(P("model", None), P("model", None)),
-               check_rep=False)
+               check_vma=False)
 a, b = jax.jit(fn)(gs)
 results["engine_w_none_degenerate"] = bool(
     np.allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5))
@@ -110,7 +111,7 @@ results["engine_w_none_degenerate"] = bool(
 # ------------------------------------------------------------------ #
 from repro.models import layers
 
-mesh_tp = jax.make_mesh((2, 4), ("data", "model"))
+mesh_tp = make_mesh((2, 4), ("data", "model"))
 b_, s_, d_, f_ = 8, 16, 32, 64
 ks = jax.random.split(jax.random.PRNGKey(3), 4)
 xin = jax.random.normal(ks[0], (b_, s_, d_), jnp.float32)
@@ -122,7 +123,7 @@ def loss(params, x):
     y = layers.swiglu(x, *params)
     return jnp.sum(y * y)
 
-with mesh_tp:
+with jax.set_mesh(mesh_tp):
     layers.set_fused_tp(False)
     ref_l, ref_g = jax.value_and_grad(loss)((wg, wu, wd), xin)
     layers.set_fused_tp(True)
@@ -137,14 +138,14 @@ results["swiglu_fused_grads"] = all(
 # ------------------------------------------------------------------ #
 # one-shot latency dispatch: correct result, counted in stats
 # ------------------------------------------------------------------ #
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 v = jax.random.normal(jax.random.PRNGKey(11), (8, 8), jnp.float32)
 vs = jax.device_put(v, NamedSharding(mesh, P("data", None)))
 before = eng.stats["latency_dispatches"]
 fn = shard_map(
     lambda x: eng.allreduce_inside(x, "data", algorithm="oneshot"),
     mesh=mesh, in_specs=P("data", None), out_specs=P("data", None),
-    check_rep=False)
+    check_vma=False)
 out = np.asarray(jax.jit(fn)(vs))
 want = np.tile(np.asarray(v).sum(0), (8, 1))
 results["oneshot_allreduce_value"] = bool(

@@ -6,8 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.multi_add import multi_add
+from repro.kernels.ops import flash_attention, multi_add
 from repro.kernels.ref import flash_attention_ref, multi_add_ref
 
 
@@ -92,7 +91,7 @@ def test_jax_chunked_attention_matches_kernel_oracle():
     (1, 64, 48, 16, 16, 16),
 ])
 def test_selective_scan_sweep(b, s, d, n, block_d, chunk):
-    from repro.kernels.selective_scan import selective_scan
+    from repro.kernels.ops import selective_scan
     from repro.kernels.ref import selective_scan_ref
     ks = jax.random.split(jax.random.PRNGKey(b * s + d), 6)
     dt = jax.nn.softplus(jax.random.normal(ks[0], (b, s, d)))
@@ -111,7 +110,7 @@ def test_selective_scan_sweep(b, s, d, n, block_d, chunk):
 
 
 def test_selective_scan_bf16_inputs():
-    from repro.kernels.selective_scan import selective_scan
+    from repro.kernels.ops import selective_scan
     from repro.kernels.ref import selective_scan_ref
     ks = jax.random.split(jax.random.PRNGKey(9), 6)
     b, s, d, n = 1, 32, 16, 8

@@ -36,12 +36,12 @@ cfg_ep = dataclasses.replace(cfg, moe_shardmap_ep=True)
 mesh = make_debug_mesh()
 
 lp, _ = forward_train(params, cfg, batch)
-with mesh:
+with jax.set_mesh(mesh):
     le, _ = jax.jit(lambda p, b: forward_train(p, cfg_ep, b))(params, batch)
 logit_err = float(np.max(np.abs(np.asarray(lp) - np.asarray(le))))
 
 g1 = jax.grad(lambda p: loss_fn(p, cfg, batch)[0])(params)
-with mesh:
+with jax.set_mesh(mesh):
     g2 = jax.jit(jax.grad(lambda p: loss_fn(p, cfg_ep, batch)[0]))(params)
 grad_err = max(float(np.max(np.abs(np.asarray(a, np.float32)
                                    - np.asarray(b, np.float32))))
@@ -70,6 +70,7 @@ def test_moe_ep_matches_gspmd():
 _EP_SCRIPT = r"""
 import json, dataclasses, functools
 import numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.models.moe import moe_ffn
 from repro.models.moe_ep import moe_ffn_ep
 
@@ -87,10 +88,10 @@ ref = np.asarray(ref)
 res = {}
 for mesh_shape, mesh_axes in (((2, 4), ("data", "model")),
                               ((2, 4), ("pod", "data"))):
-    mesh = jax.make_mesh(mesh_shape, mesh_axes)
+    mesh = make_mesh(mesh_shape, mesh_axes)
     outs = {}
     for algo in ("lax", "auto", "hierarchical", "flat"):
-        with mesh:
+        with jax.set_mesh(mesh):
             out, _ = jax.jit(functools.partial(
                 moe_ffn_ep, top_k=K, algorithm=algo))(x, router, wg,
                                                       wu, wd)
@@ -102,14 +103,14 @@ for mesh_shape, mesh_axes in (((2, 4), ("data", "model")),
         np.max(np.abs(outs["auto"] - outs["lax"])))
 
 # gradient flow through the engine exchange == through bare lax
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 def loss(params, algo):
     r, a, b, c = params
-    with mesh:
-        out, _ = moe_ffn_ep(x, r, a, b, c, top_k=K, algorithm=algo)
+    out, _ = moe_ffn_ep(x, r, a, b, c, top_k=K, algorithm=algo)
     return jnp.sum(out ** 2)
-g_lax = jax.jit(jax.grad(lambda p: loss(p, "lax")))((router, wg, wu, wd))
-g_eng = jax.jit(jax.grad(lambda p: loss(p, "auto")))((router, wg, wu, wd))
+with jax.set_mesh(mesh):
+    g_lax = jax.jit(jax.grad(lambda p: loss(p, "lax")))((router, wg, wu, wd))
+    g_eng = jax.jit(jax.grad(lambda p: loss(p, "auto")))((router, wg, wu, wd))
 res["grad_err"] = max(
     float(np.max(np.abs(np.asarray(u) - np.asarray(v))))
     for u, v in zip(jax.tree.leaves(g_lax), jax.tree.leaves(g_eng)))

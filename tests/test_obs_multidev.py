@@ -19,8 +19,9 @@ pytestmark = [pytest.mark.multidev, pytest.mark.slow]
 
 _SCRIPT = r"""
 import json, numpy as np, jax, jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro import obs
 from repro.collectives.api import get_engine
 from repro.collectives.engine import CollectiveEngine
@@ -32,13 +33,12 @@ eng = CollectiveEngine(cache_path=None)
 set_engine(eng)
 tracer = obs.enable_tracing(measure=True)
 
-devs = np.array(jax.devices()).reshape(2, 4)
-mesh = Mesh(devs, ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 axes = ("pod", "data")
 
 def run(fn, x, in_spec, out_spec):
     w = shard_map(fn, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
-                  check_rep=False)
+                  check_vma=False)
     return jax.block_until_ready(jax.jit(w)(x))
 
 x = jnp.arange(4096, dtype=jnp.float32)

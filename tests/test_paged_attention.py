@@ -18,6 +18,13 @@ from repro.models import (decode_step, decode_step_paged, forward_paged,
                           forward_train, init_pages, init_params, prefill)
 
 
+def _kernel(q, kp, vp, bt, lengths):
+    """The kernel reads the pool with heads merged: [N, bs, Hkv * D]."""
+    n, bs = kp.shape[:2]
+    return paged_attention(q, kp.reshape(n, bs, -1), vp.reshape(n, bs, -1),
+                           bt, lengths, interpret=True)
+
+
 # ------------------------- kernel vs oracle --------------------------- #
 @pytest.mark.parametrize("b,h,hkv,d,bs,n,m", [
     (2, 4, 2, 16, 8, 10, 3),
@@ -33,7 +40,7 @@ def test_paged_kernel_matches_ref(b, h, hkv, d, bs, n, m):
     ids = rng.permutation(np.arange(1, n))[:b * m]
     bt = jnp.asarray(np.resize(ids, (b, m)).astype(np.int32))
     lengths = jnp.asarray(rng.integers(1, m * bs + 1, size=(b,)), jnp.int32)
-    got = paged_attention(q, kp, vp, bt, lengths, interpret=True)
+    got = _kernel(q, kp, vp, bt, lengths)
     want = paged_attention_ref(q, kp, vp, bt, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -47,7 +54,7 @@ def test_paged_kernel_bf16():
     vp = jnp.asarray(rng.normal(size=(n, bs, hkv, d))).astype(jnp.bfloat16)
     bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     lengths = jnp.asarray([9, 16], jnp.int32)
-    got = paged_attention(q, kp, vp, bt, lengths, interpret=True)
+    got = _kernel(q, kp, vp, bt, lengths)
     want = paged_attention_ref(q, kp, vp, bt, lengths)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -64,11 +71,11 @@ def test_paged_kernel_short_rows_ignore_stale_blocks():
     vp = jnp.asarray(rng.normal(size=(n, bs, hkv, d)), jnp.float32)
     bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     lengths = jnp.asarray([3, 11], jnp.int32)
-    base = paged_attention(q, kp, vp, bt, lengths, interpret=True)
+    base = _kernel(q, kp, vp, bt, lengths)
     # clobber everything outside the valid prefixes
     kp2 = kp.at[2].set(99.0).at[4, 3:].set(-99.0).at[5].set(99.0)
     vp2 = vp.at[2].set(99.0).at[4, 3:].set(-99.0).at[5].set(99.0)
-    again = paged_attention(q, kp2, vp2, bt, lengths, interpret=True)
+    again = _kernel(q, kp2, vp2, bt, lengths)
     np.testing.assert_allclose(np.asarray(base), np.asarray(again),
                                rtol=1e-6, atol=1e-6)
 

@@ -133,7 +133,8 @@ _SCRIPT = r"""
 import functools, json
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.collectives.api import (allreduce_multi_inside,
                                    reduce_scatter_multi_inside,
                                    allgather_multi_inside,
@@ -141,13 +142,13 @@ from repro.collectives.api import (allreduce_multi_inside,
 from repro.collectives.overlap import bucketed_allreduce
 
 results = {}
-mesh24 = jax.make_mesh((2, 4), ("pod", "data"))
-mesh222 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh24 = make_mesh((2, 4), ("pod", "data"))
+mesh222 = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 def run(mesh, fn, x, in_spec, out_spec):
     f = shard_map(fn, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
-                  check_rep=False)
-    with mesh:
+                  check_vma=False)
+    with jax.set_mesh(mesh):
         return np.asarray(jax.jit(f)(x))
 
 for mesh, axes, tag in ((mesh24, ("pod", "data"), "24"),

@@ -55,6 +55,72 @@ def test_serving_loop_decodes():
     assert all(len(v) == 4 for v in results.values())
 
 
+def test_serve_cli_reduced_reports_sizes_and_memory(monkeypatch, tmp_path,
+                                                   capsys):
+    from repro.launch import serve
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    serve.main(["--reduced", "--requests", "2", "--batch", "2",
+                "--prompt-len", "8", "--new-tokens", "4"])
+    out = capsys.readouterr().out
+    assert f"compile cache: {tmp_path}" in out
+    assert "2 layers, d_model 64" in out and "kv pool" in out
+    assert "decode program: arguments" in out
+    assert "2 requests, 8 tokens" in out
+
+
+def test_compile_cache_takes_the_environment_dir(monkeypatch, tmp_path):
+    from repro.launch.compile_cache import ENV_VAR, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(ENV_VAR, str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before   # JAX reads it
+
+
+def test_compile_cache_defaults_to_var_in_the_checkout(monkeypatch):
+    import os
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch.compile_cache import (DEFAULT_DIR, ENV_VAR,
+                                            enable_compile_cache)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert DEFAULT_DIR == os.path.join(repo, "var", "jax_cache")
+    monkeypatch.delenv(ENV_VAR, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert enable_compile_cache() == DEFAULT_DIR
+        assert jax.config.jax_compilation_cache_dir == DEFAULT_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("arch", ["minicpm-2b", "falcon-mamba-7b"])
+def test_device_param_build_matches_eager_init(arch):
+    """The jitted build (one program, stacked layers made by vmap) gives
+    the eager build's weights (within one ulp: a fused ``log`` may round
+    differently), and the vmapped stack equals the per-layer list
+    stacked."""
+    from repro.configs import get_config
+    from repro.launch.serve import build_params
+    from repro.models import init_params
+    from repro.models import transformer as T
+    cfg = get_config(arch).reduced()
+    eager = init_params(jax.random.PRNGKey(0), cfg)
+    built = build_params(cfg, seed=0)
+    assert jax.tree.structure(eager) == jax.tree.structure(built)
+    for a, b in zip(jax.tree.leaves(eager), jax.tree.leaves(built)):
+        assert a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), atol=0,
+                                   rtol=float(jax.numpy.finfo(a.dtype).eps))
+    if cfg.family != "ssm":
+        keys = jax.random.split(jax.random.PRNGKey(1), cfg.num_layers)
+        make = lambda k: T._decoder_layer(k, cfg, cfg.activation_dtype)
+        stacked = T._stacked(make, keys)
+        listed = T._stack([make(k) for k in keys])
+        for a, b in zip(jax.tree.leaves(stacked), jax.tree.leaves(listed)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_microbatched_step_matches_full_batch():
     from repro.models import init_params
     from repro.optim.adamw import AdamWConfig

@@ -1,0 +1,111 @@
+"""The readers of the server's spans (``host_step_ms``, ``host_idle_ms``,
+``decode_occupancy``, ``prefill_p50_ms``) on planes built by hand, and on
+the recorded v5e trace, which holds no ``serve.*`` event."""
+
+import dataclasses
+import types
+
+import pytest
+
+from bench import harness, session
+from bench.metrics import _spans
+from bench.tests.test_trace_reduce import DATA, Line, Plane
+
+READERS = ("host_step_ms", "host_idle_ms", "decode_occupancy", "prefill_p50_ms")
+
+
+@dataclasses.dataclass
+class Ev:
+    name: str
+    start_ns: int
+    duration_ns: int
+    stats: tuple = ()
+
+
+def ev(name, a, b, **stats):
+    return Ev(name, a, b - a, tuple(stats.items()))
+
+
+def planes():
+    host = Plane(
+        "/host:CPU",
+        [
+            Line(
+                "python",
+                [
+                    ev("bench.step", 1000, 2000),
+                    ev("serve.step", 1000, 1900, step=0),
+                    ev("serve.schedule", 1000, 1100, queued=0),
+                    ev("serve.decode.batch", 1100, 1200, rows=3),
+                    ev("serve.decode", 1200, 1300, rows=3, batch=4),
+                    ev("serve.sample", 1300, 1350, rows=3),
+                    ev("serve.wait", 1350, 1800),
+                    ev("serve.append", 1800, 1900, tokens=3),
+                    ev("bench.step", 2500, 3000),
+                    ev("serve.step", 2500, 2950, step=1),
+                    ev("serve.prefill", 2500, 2600, rid=7, tokens=32, ctx=0),
+                    ev("serve.decode", 2600, 2650, rows=2, batch=4),
+                    ev("serve.wait", 2650, 2850),
+                    ev("_server.py:293_decode_batch", 1100, 1200),
+                ],
+            )
+        ],
+    )
+    dev = Plane("/device:TPU:0", [Line("XLA Ops", [Ev("fusion", 1250, 500), Ev("harness_op", 2000, 100), Ev("paged_attention", 2620, 180)])])
+    return [host, dev]
+
+
+def fake_run(requests=(), trace=True):
+    w = harness.Window(100.0, 10.0, [], {}, [], 0, 0.0)
+    w.requests = list(requests)
+    return types.SimpleNamespace(window=w, trace=object() if trace else None)
+
+
+@pytest.fixture
+def hand_built(monkeypatch):
+    monkeypatch.setattr(_spans, "spans", lambda run: _spans.from_planes(planes()))
+
+
+def test_host_step_less_waits(hand_built):
+    # step 0: 900 ns less a 450 ns wait; step 1: 450 less 200
+    assert session.load_reader("host_step_ms")(fake_run()) == pytest.approx((450 + 250) / 2 / 1e6)
+
+
+def test_host_idle_per_step_and_by_span(hand_built, capsys):
+    # step 0 [1000, 1900] busy 500; step 1 [2500, 2950] busy 180
+    assert session.load_reader("host_idle_ms")(fake_run()) == pytest.approx((400 + 270) / 2 / 1e6)
+    line = capsys.readouterr().err
+    # gaps [1000, 1250] mid 1125, [1750, 2000] mid 1875, [2100, 2620] mid 2360, [2800, 3000] mid 2900
+    got = dict(kv.rsplit(" ", 1) for kv in line.split(": ", 2)[2].strip().split(", "))
+    assert {k: float(v) for k, v in got.items()} == pytest.approx({"harness": 520e-9, "serve.decode.batch": 250e-9, "serve.append": 250e-9, "serve.step": 200e-9})
+
+
+def test_decode_occupancy(hand_built):
+    assert session.load_reader("decode_occupancy")(fake_run()) == pytest.approx(100 * (3 / 4 + 2 / 4) / 2)
+
+
+def test_spans_window_and_order():
+    sp = _spans.from_planes(iter(planes()))  # ProfileData's planes read once
+    assert (sp.lo, sp.hi) == (1000, 3000)
+    assert sp.busy == [(1250, 1750), (2000, 2100), (2620, 2800)]
+    assert [e[0] for e in sp.events[:2]] == ["serve.step", "serve.schedule"]
+    assert all(e[0].startswith("serve.") for e in sp.events)
+    assert sp.named("serve.decode")[1][3] == {"rows": 2, "batch": 4}
+
+
+def test_prefill_p50_from_admission_in_window():
+    r = lambda admit, first: types.SimpleNamespace(admit_t=admit, first_token_t=first)
+    reqs = [r(101.0, 101.2), r(105.0, 105.5), r(103.0, 103.3), r(99.0, 100.1), r(109.0, None), r(111.0, 111.1)]
+    assert session.load_reader("prefill_p50_ms")(fake_run(reqs)) == pytest.approx(300.0)
+
+
+def test_readers_none_without_serving_spans(monkeypatch):
+    # the recorded chip trace has bench.step spans and device ops, and
+    # no serve.* event (the program predates them); requests with no
+    # admit_t are what such a program hands the reader
+    monkeypatch.setattr(_spans, "trace_dir", lambda: DATA)
+    _spans._load.cache_clear()
+    old = types.SimpleNamespace(first_token_t=101.0)
+    for name in READERS:
+        assert session.load_reader(name)(fake_run([old])) is None, name
+        assert session.load_reader(name)(fake_run([old], trace=False)) is None, name

@@ -835,10 +835,10 @@ class CollectiveEngine:
         """Open a collective span carrying every key the trace schema
         requires (``REQUIRED_COLLECTIVE_ARGS``), so a span is
         conformant even when the op bypasses the model (native/forced
-        paths fill the rest in :meth:`_finish_collective`)."""
+        paths fill the rest in :meth:`_finish_collective`).  With the
+        in-memory tracer off it is the span's profiler annotation
+        alone."""
         tracer = obs_trace.get_tracer()
-        if not tracer.enabled:
-            return obs_trace.NULL_SPAN
         if isinstance(axis_or_axes, (tuple, list)):
             names = tuple(str(a) for a in axis_or_axes)
         else:

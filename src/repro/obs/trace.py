@@ -1,5 +1,6 @@
-"""Span tracing for engine collectives: structured spans, Chrome-trace
-export, opt-in blocking measurement.
+"""Span tracing for engine collectives and the serving loop: structured
+spans, Chrome-trace export, opt-in blocking measurement, and a profiler
+annotation for every span.
 
 Every engine collective call emits one *span* carrying the op, the
 axes/sizes it ran over, payload bytes, the chosen plan
@@ -21,6 +22,16 @@ Two measurement regimes, because engine calls run in two worlds:
   taxes the hot path by default) the span blocks on the result and
   ``measured_s`` is real wall time.
 
+Every span has two sinks.  The in-memory record (the :class:`Span`,
+exported as Chrome JSON) is opt-in: :func:`enable_tracing`.  The
+profiler sink is always there: ``Tracer.span`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name with the span's args
+as event stats, so while a profiler session is on (``jax.profiler.
+start_trace``) the span lands in the ``.xplane.pb`` on the same clock as
+the device's ops; with no session it costs about a microsecond.  The
+serving loop (``ContinuousBatchingServer.step_once``) opens
+:data:`CAT_SERVE` spans; they never block.
+
 ``measured_s`` for traced spans can be backfilled afterwards with
 :func:`repro.obs.replay.measure_spans`, which re-executes each unique
 collective signature eagerly on the mesh and times it.
@@ -41,10 +52,12 @@ import time
 from typing import Any, Dict, Iterator, List, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 
 #: span categories (the ``cat`` field of the Chrome events)
 CAT_COLLECTIVE = "collective"
 CAT_PHASE = "phase"
+CAT_SERVE = "serve"
 
 #: schema tag written into the trace metadata; bump when span args
 #: change incompatibly.
@@ -105,26 +118,45 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class Annotation(TraceAnnotation):
+    """A span's profiler sink: a ``TraceMe`` carrying the span's name
+    and args.  Alone, it is what ``Tracer.span`` hands out while the
+    in-memory tracer is off, so it takes the no-op span's calls."""
+
+    __slots__ = ()
+
+    def set(self, **kw: Any) -> None:
+        return None
+
+    def finish_result(self, result: Any, block: Optional[bool] = None
+                      ) -> None:
+        return None
+
+
 def _is_traced(x: Any) -> bool:
     return isinstance(x, jax.core.Tracer)
 
 
 class _SpanContext:
     """Context manager binding a live :class:`Span` to the tracer's
-    thread-local stack."""
+    thread-local stack (and its profiler annotation)."""
 
-    __slots__ = ("_tracer", "span", "_finished")
+    __slots__ = ("_tracer", "span", "_finished", "_annotation")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span,
+                 annotation: Annotation):
         self._tracer = tracer
         self.span = span
         self._finished = False
+        self._annotation = annotation
 
     def __enter__(self) -> "_SpanContext":
         self._tracer._push(self.span)
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc: Any) -> None:
+        self._annotation.__exit__(*exc)
         if not self._finished:
             self.span.dur = self._tracer._now() - self.span.t0
         self._tracer._pop(self.span)
@@ -151,7 +183,7 @@ class _SpanContext:
 
 class Tracer:
     """Collects spans; disabled by default (every ``span()`` call
-    returns the shared no-op)."""
+    returns a bare profiler :class:`Annotation`)."""
 
     def __init__(self, enabled: bool = False, measure: bool = False,
                  max_spans: int = 200_000,
@@ -188,14 +220,15 @@ class Tracer:
 
     # ------------------------------------------------------------------ #
     def span(self, name: str, cat: str = CAT_COLLECTIVE, **args: Any):
-        """Open a span (context manager).  Returns the shared no-op
-        when tracing is disabled."""
+        """Open a span (context manager).  It is always annotated for
+        the profiler; it is recorded in memory only while tracing is
+        enabled (else the bare :class:`Annotation` is returned)."""
         if not self.enabled:
-            return NULL_SPAN
+            return Annotation(name, **args)
         with self._lock:
             if len(self._spans) >= self.max_spans:
                 self.dropped += 1
-                return NULL_SPAN
+                return Annotation(name, **args)
             sid = self._next_id
             self._next_id += 1
             st = self._stack()
@@ -204,7 +237,7 @@ class Tracer:
                       t0=self._now(), tid=threading.get_ident() & 0xFFFF,
                       args=dict(args))
             self._spans.append(sp)
-        return _SpanContext(self, sp)
+        return _SpanContext(self, sp, Annotation(name, **args))
 
     def current_span(self):
         """The innermost live span on this thread (the one a nested
@@ -348,8 +381,9 @@ def disable_tracing() -> None:
     get_tracer().enabled = False
 
 
-__all__ = ["Span", "Tracer", "NULL_SPAN", "TRACE_SCHEMA",
-           "CAT_COLLECTIVE", "CAT_PHASE", "REQUIRED_COLLECTIVE_ARGS",
+__all__ = ["Span", "Tracer", "NULL_SPAN", "Annotation", "TRACE_SCHEMA",
+           "CAT_COLLECTIVE", "CAT_PHASE", "CAT_SERVE",
+           "REQUIRED_COLLECTIVE_ARGS",
            "get_tracer", "set_tracer", "enable_tracing", "disable_tracing",
            "load_chrome_trace", "spans_from_events", "collective_spans",
            "validate_spans"]

@@ -80,6 +80,7 @@ class Request:
     _chain_keys: List[bytes] = dataclasses.field(default_factory=list)
     _cache_upto: int = 0                # table blocks already inserted
     arrival_t: float = 0.0
+    admit_t: Optional[float] = None     # first admission (arrival's clock)
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
     admit_step: Optional[int] = None
@@ -232,10 +233,14 @@ class Scheduler:
         req.ctx_len = cached            # cacheable requests have n_soft=0
         return table
 
-    def admit(self, step: int) -> List[Request]:
+    def admit(self, step: int, now: Optional[float] = None
+              ) -> List[Request]:
         """FCFS-fill free slots from the queue; all-or-nothing block
         grants keep admission atomic.  Stops at the first request that
-        does not fit (no starvation of large requests)."""
+        does not fit (no starvation of large requests).  ``now`` (on
+        ``arrival_t``'s clock) stamps ``admit_t`` at a request's first
+        admission."""
+        now = time.monotonic() if now is None else now
         admitted = []
         for i in range(self.batch_size):
             if self.slots[i] is not None or not self.queue:
@@ -247,8 +252,8 @@ class Scheduler:
             self.queue.popleft()
             req.table = table
             req.state = PREFILLING
-            req.admit_step = step if req.admit_step is None else \
-                req.admit_step
+            if req.admit_step is None:
+                req.admit_step, req.admit_t = step, now
             req._admit_seq = self._admit_seq
             self._admit_seq += 1
             self.slots[i] = req

@@ -10,6 +10,14 @@ jitted paged model entry points (``repro.models.paged``):
   idle slots masked to the scratch block,
 * one jitted **sample(+gather)** program.
 
+The programs are named functions, so a profile's ``XLA Modules`` line
+reads ``jit_serve_prefill``, ``jit_serve_decode``, ``jit_serve_greedy``,
+``jit_serve_sample``, ``jit_serve_sample_notopk`` and
+``jit_serve_copy_blocks`` (``*_gather`` under DP).  ``step_once``
+opens ``serve.*`` spans (``repro.obs.trace``, category ``serve``) at
+its layer boundaries; they reach a profile on the device's clock, and
+the in-memory tracer when it is enabled.
+
 Data-parallel serving (``mesh=``) stripes the slot rows over the DP
 axis.  Every host-side scheduling decision needs the *global* token
 vector, so per-shard sampled tokens are assembled with the
@@ -37,6 +45,7 @@ from jax import shard_map
 from repro.collectives.api import get_engine
 from repro.models.paged import (copy_blocks, decode_step_paged,
                                 forward_paged, init_pages, supports_paged)
+from repro.obs.trace import CAT_SERVE, get_tracer
 from repro.serving.blocks import BlockAllocator
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.sampling import sample_tokens
@@ -51,6 +60,25 @@ from repro.serving.telemetry import Telemetry, TelemetrySnapshot
 #: function of (rid, position))
 _SAMPLE_STRIDE = 1 << 20
 _SAMPLE_MOD = 1 << 31
+
+
+def _span(name: str, **args):
+    """A serving span on the process tracer (profiler annotation always,
+    in-memory record when tracing is enabled)."""
+    return get_tracer().span(name, CAT_SERVE, **args)
+
+
+# The jitted programs are functions named ``serve_*`` (module-level,
+# or nested where they close over the model): a program's name is its
+# module's name in a profile (``jit_serve_greedy``); a lambda would
+# read ``jit__lambda`` and a bare ``functools.partial``
+# ``jit__unknown``.
+def serve_copy_blocks(pages, src, dst):
+    return copy_blocks(pages, src, dst)
+
+
+def serve_greedy(logits):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,34 +147,39 @@ class ContinuousBatchingServer:
 
         key = self.key
 
-        def _prefill(params, pages, tokens, bt, ctx, new_len, soft=None):
+        def serve_prefill(params, pages, tokens, bt, ctx, new_len,
+                          soft=None):
             batch = {"tokens": tokens}
             if soft is not None:
                 batch["soft_emb"] = soft
             return forward_paged(params, cfg, pages, batch, bt, ctx,
                                  new_len, use_kernel=False)
 
+        def serve_decode(params, pages, tokens, bt, ctx):
+            return decode_step_paged(params, cfg, pages, {"tokens": tokens},
+                                     bt, ctx, use_kernel=use_kernel)
+
+        def serve_sample(logits, sids, temps, topks):
+            return sample_tokens(logits, sids, temps, key, topks)
+
+        # batches with no top-k row skip the cutoff sort (trace-time 0)
+        def serve_sample_notopk(logits, sids, temps):
+            return sample_tokens(logits, sids, temps, key, 0)
+
         # the page pool is dead after each call (run() reassigns it), so
         # donate it where the backend supports donation -- decode then
         # updates the cache in place instead of copying the whole pool
         donate = (1,) if jax.default_backend() != "cpu" else ()
-        self._prefill_fn = jax.jit(_prefill, donate_argnums=donate)
+        self._prefill_fn = jax.jit(serve_prefill, donate_argnums=donate)
         # copy-on-write pool copies (whole blocks src -> dst); donated
         # like the other pool-threading programs
-        self._copy_fn = jax.jit(copy_blocks,
+        self._copy_fn = jax.jit(serve_copy_blocks,
                                 donate_argnums=(0,) if donate else ())
-        self._decode_fn = jax.jit(
-            lambda p, pg, t, b, c: decode_step_paged(
-                p, cfg, pg, {"tokens": t}, b, c, use_kernel=use_kernel),
-            donate_argnums=donate)
-        self._sample_fn = jax.jit(
-            lambda lg, sid, tmp, tk: sample_tokens(lg, sid, tmp, key, tk))
-        # batches with no top-k row skip the cutoff sort (trace-time 0)
-        self._sample_notopk_fn = jax.jit(
-            lambda lg, sid, tmp: sample_tokens(lg, sid, tmp, key, 0))
+        self._decode_fn = jax.jit(serve_decode, donate_argnums=donate)
+        self._sample_fn = jax.jit(serve_sample)
+        self._sample_notopk_fn = jax.jit(serve_sample_notopk)
         # all-greedy batches (the common case) skip the sampling math
-        greedy = lambda lg: jnp.argmax(lg, axis=-1).astype(jnp.int32)
-        self._greedy_fn = jax.jit(greedy)
+        self._greedy_fn = jax.jit(serve_greedy)
         self._sample_gather_fn = None
         self._sample_notopk_gather_fn = None
         self._greedy_gather_fn = None
@@ -167,27 +200,21 @@ class ContinuousBatchingServer:
             self.assembly_regime = ("latency" if dec.algorithm == "oneshot"
                                     else "bandwidth")
 
-            def _gathered(fn):
+            def _gathered(fn, in_specs):
                 # per-shard tokens assembled by the engine's cached
-                # model-driven allgather
+                # model-driven allgather, as ``<program>_gather``
                 def local(lg, *rest):
                     return eng.allgather_inside(fn(lg, *rest), dp_axis)
-                return local
+                local.__name__ = f"{fn.__name__}_gather"
+                return jax.jit(shard_map(local, mesh=mesh,
+                                         in_specs=in_specs, out_specs=P(),
+                                         check_vma=False))
 
             row_specs = (P(dp_axis),) * 4
-            self._sample_gather_fn = jax.jit(shard_map(
-                _gathered(lambda lg, sid, tmp, tk:
-                          sample_tokens(lg, sid, tmp, key, tk)),
-                mesh=mesh, in_specs=row_specs, out_specs=P(),
-                check_vma=False))
-            self._sample_notopk_gather_fn = jax.jit(shard_map(
-                _gathered(lambda lg, sid, tmp:
-                          sample_tokens(lg, sid, tmp, key, 0)),
-                mesh=mesh, in_specs=row_specs[:3], out_specs=P(),
-                check_vma=False))
-            self._greedy_gather_fn = jax.jit(shard_map(
-                _gathered(greedy), mesh=mesh, in_specs=P(dp_axis),
-                out_specs=P(), check_vma=False))
+            self._sample_gather_fn = _gathered(serve_sample, row_specs)
+            self._sample_notopk_gather_fn = _gathered(serve_sample_notopk,
+                                                      row_specs[:3])
+            self._greedy_gather_fn = _gathered(serve_greedy, P(dp_axis))
 
     def compile_decode(self):
         """Ahead-of-time compile of the decode program at this server's
@@ -223,9 +250,10 @@ class ContinuousBatchingServer:
 
     # ------------------------------------------------------------------ #
     def _sample_rows(self, logits: jax.Array, reqs: List[Request],
-                     rows: List[int], gathered: bool) -> np.ndarray:
-        """logits [B, V] -> host tokens [B]; per-(request, position)
-        keys make the result independent of slot placement and DP."""
+                     rows: List[int], gathered: bool) -> jax.Array:
+        """logits [B, V] -> tokens [B], dispatched and not waited on;
+        per-(request, position) keys make the result independent of
+        slot placement and DP."""
         b = logits.shape[0]
         sids = np.zeros((b,), np.int32)
         temps = np.zeros((b,), np.float32)
@@ -237,15 +265,23 @@ class ContinuousBatchingServer:
             topks[row] = req.sampling.top_k or self.top_k
         if not np.any(temps > 0):       # all-greedy hot path: argmax only
             fn = self._greedy_gather_fn if gathered else self._greedy_fn
-            return np.asarray(fn(logits))
+            return fn(logits)
         if not np.any(topks > 0):       # no cutoff sort needed
             fn = (self._sample_notopk_gather_fn if gathered
                   else self._sample_notopk_fn)
-            return np.asarray(fn(logits, jnp.asarray(sids),
-                                 jnp.asarray(temps)))
+            return fn(logits, jnp.asarray(sids), jnp.asarray(temps))
         fn = self._sample_gather_fn if gathered else self._sample_fn
-        return np.asarray(fn(logits, jnp.asarray(sids), jnp.asarray(temps),
-                             jnp.asarray(topks)))
+        return fn(logits, jnp.asarray(sids), jnp.asarray(temps),
+                  jnp.asarray(topks))
+
+    def _next_tokens(self, logits: jax.Array, reqs: List[Request],
+                     rows: List[int], gathered: bool) -> np.ndarray:
+        """Sample the rows' next tokens and wait for them on the host:
+        the step's one wait on the device."""
+        with _span("serve.sample", rows=len(rows)):
+            toks = self._sample_rows(logits, reqs, rows, gathered)
+        with _span("serve.wait"):
+            return np.asarray(toks)
 
     def _append_token(self, req: Request, token: int) -> None:
         req.out.append(int(token))
@@ -263,38 +299,43 @@ class ContinuousBatchingServer:
     def _run_prefill_chunk(self, chunk: PrefillChunk) -> None:
         req, start, n = chunk.req, chunk.start, chunk.length
         replay = req.replay_tokens
-        tokens = np.zeros((1, self.prefill_chunk), np.int32)
-        tokens[0, :n] = replay[start:start + n]
-        bt = np.zeros((1, self.max_blocks_per_seq), np.int32)
-        bt[0, :len(req.table.blocks)] = req.table.blocks
-        ctx = np.asarray([req.ctx_len], np.int32)
-        new_len = np.asarray([n], np.int32)
-        if start == 0 and req.soft_emb is not None:
-            logits, self.pages = self._prefill_fn(
-                self.params, self.pages, tokens, bt, ctx, new_len,
-                req.soft_emb)
-            req.ctx_len += req.n_soft
-        else:
-            logits, self.pages = self._prefill_fn(
-                self.params, self.pages, tokens, bt, ctx, new_len)
-        req.prefilled += n
-        req.ctx_len += n
-        self.telemetry.record_prefill_tokens(n)
-        self.scheduler.note_prefilled(req)
-        if req.prefilled == len(replay):
+        with _span("serve.prefill", rid=req.rid, tokens=n, ctx=req.ctx_len):
+            tokens = np.zeros((1, self.prefill_chunk), np.int32)
+            tokens[0, :n] = replay[start:start + n]
+            bt = np.zeros((1, self.max_blocks_per_seq), np.int32)
+            bt[0, :len(req.table.blocks)] = req.table.blocks
+            ctx = np.asarray([req.ctx_len], np.int32)
+            new_len = np.asarray([n], np.int32)
+            if start == 0 and req.soft_emb is not None:
+                logits, self.pages = self._prefill_fn(
+                    self.params, self.pages, tokens, bt, ctx, new_len,
+                    req.soft_emb)
+                req.ctx_len += req.n_soft
+            else:
+                logits, self.pages = self._prefill_fn(
+                    self.params, self.pages, tokens, bt, ctx, new_len)
+            req.prefilled += n
+            req.ctx_len += n
+            self.telemetry.record_prefill_tokens(n)
+            self.scheduler.note_prefilled(req)
             # prompt fully cached: the chunk's last valid position
             # yields this request's next token (its first, unless it
             # was preempted mid-decode and replayed)
+            last = logits[:, n - 1] if req.prefilled == len(replay) else None
+        if last is not None:
             req.state = RUNNING
-            tok = self._sample_rows(logits[:, n - 1], [req], [0],
-                                    gathered=False)
-            self._append_token(req, int(tok[0]))
+            tok = self._next_tokens(last, [req], [0], gathered=False)
+            with _span("serve.append", tokens=1):
+                self._append_token(req, int(tok[0]))
 
     def decode_batch(self):
         """The next decode step's inputs: (running [(row, request)],
         [tokens [B, 1], block tables [B, M], context lengths [B]]),
         idle rows zero (their writes land in the scratch block)."""
         running = self.scheduler.running()
+        return running, self._decode_args(running)
+
+    def _decode_args(self, running) -> List[jax.Array]:
         tokens = np.zeros((self.batch, 1), np.int32)
         bt = np.zeros((self.batch, self.max_blocks_per_seq), np.int32)
         ctx = np.zeros((self.batch,), np.int32)
@@ -305,20 +346,25 @@ class ContinuousBatchingServer:
         args = [jnp.asarray(tokens), jnp.asarray(bt), jnp.asarray(ctx)]
         if self.mesh is not None:
             args = [jax.device_put(a, self._row_sharding) for a in args]
-        return running, args
+        return args
 
     def _run_decode(self) -> None:
-        running, args = self.decode_batch()
+        running = self.scheduler.running()
+        with _span("serve.decode.batch", rows=len(running)):
+            args = self._decode_args(running)
         rows = [i for i, _ in running]
         reqs = [r for _, r in running]
-        with self._on_mesh():
-            logits, self.pages = self._decode_fn(self.params, self.pages,
-                                                 *args)
-        toks = self._sample_rows(logits[:, 0], reqs, rows,
+        with _span("serve.decode", rows=len(rows), batch=self.batch):
+            with self._on_mesh():
+                logits, self.pages = self._decode_fn(self.params,
+                                                     self.pages, *args)
+            last = logits[:, 0]
+        toks = self._next_tokens(last, reqs, rows,
                                  gathered=self.mesh is not None)
-        for i, req in running:
-            req.ctx_len += 1
-            self._append_token(req, int(toks[i]))
+        with _span("serve.append", tokens=len(running)):
+            for i, req in running:
+                req.ctx_len += 1
+                self._append_token(req, int(toks[i]))
 
     # ------------------------------------------------------------------ #
     def has_work(self) -> bool:
@@ -330,47 +376,53 @@ class ContinuousBatchingServer:
         ``run()`` loops over and a fleet drives in lockstep waves; a
         stalled scheduler (queued work that can never be admitted)
         raises."""
-        results: Dict[int, List[int]] = {}
-        for req in self.scheduler.retire_finished():
-            results[req.rid] = req.out
-        now = self.telemetry.now()
-        for req in self.scheduler.admit(self._step):
-            self.telemetry.record_queue_wait(now - req.arrival_t)
-            if req.cached_prefix_tokens:
-                self.telemetry.record_cached_prefix(
-                    req.cached_prefix_tokens)
-        cows = self.scheduler.drain_cow_copies()
-        if cows:
-            # private replacements for shared blocks about to be
-            # written; must land before this step's prefill chunks
-            src = jnp.asarray([s for s, _ in cows], jnp.int32)
-            dst = jnp.asarray([d for _, d in cows], jnp.int32)
-            self.pages = self._copy_fn(self.pages, src, dst)
-        if not self.scheduler.active():
-            if self.scheduler.queue:
-                raise RuntimeError(
-                    "serving stalled: queued request cannot be "
-                    "admitted (KV block pool too small?)")
-            return StepOutcome(results, decoded=False, progressed=False)
-        plan = self.scheduler.prefill_plan()
-        for chunk in plan:
-            self._run_prefill_chunk(chunk)
-        decoded = False
-        if self.scheduler.any_running():
-            for _ in self.scheduler.grow_for_decode():
-                self.telemetry.record_preemption()
-            if self.scheduler.any_running():
-                self._run_decode()
-                decoded = True
-        self.telemetry.record_step(decoded=decoded,
-                                   prefill_chunks=len(plan),
-                                   kv_occupancy=self.allocator.occupancy,
-                                   queue_depth=len(self.scheduler.queue))
-        self._step += 1
-        if not plan and not decoded and not any(
-                r.done for r in self.scheduler.slots if r):
-            raise RuntimeError("scheduler made no progress")
-        return StepOutcome(results, decoded=decoded, progressed=True)
+        sched = self.scheduler
+        with _span("serve.step", step=self._step):
+            results: Dict[int, List[int]] = {}
+            with _span("serve.schedule", queued=len(sched.queue)):
+                for req in sched.retire_finished():
+                    results[req.rid] = req.out
+                now = self.telemetry.now()
+                for req in sched.admit(self._step, now):
+                    self.telemetry.record_queue_wait(now - req.arrival_t)
+                    if req.cached_prefix_tokens:
+                        self.telemetry.record_cached_prefix(
+                            req.cached_prefix_tokens)
+                cows = sched.drain_cow_copies()
+                if not sched.active():
+                    if sched.queue:
+                        raise RuntimeError(
+                            "serving stalled: queued request cannot be "
+                            "admitted (KV block pool too small?)")
+                    return StepOutcome(results, decoded=False,
+                                       progressed=False)
+                plan = sched.prefill_plan()
+            if cows:
+                # private replacements for shared blocks about to be
+                # written; must land before this step's prefill chunks
+                with _span("serve.cow", blocks=len(cows)):
+                    src = jnp.asarray([s for s, _ in cows], jnp.int32)
+                    dst = jnp.asarray([d for _, d in cows], jnp.int32)
+                    self.pages = self._copy_fn(self.pages, src, dst)
+            for chunk in plan:
+                self._run_prefill_chunk(chunk)
+            decoded = False
+            if sched.any_running():
+                with _span("serve.schedule", queued=len(sched.queue)):
+                    for _ in sched.grow_for_decode():
+                        self.telemetry.record_preemption()
+                if sched.any_running():
+                    self._run_decode()
+                    decoded = True
+            self.telemetry.record_step(decoded=decoded,
+                                       prefill_chunks=len(plan),
+                                       kv_occupancy=self.allocator.occupancy,
+                                       queue_depth=len(sched.queue))
+            self._step += 1
+            if not plan and not decoded and not any(
+                    r.done for r in sched.slots if r):
+                raise RuntimeError("scheduler made no progress")
+            return StepOutcome(results, decoded=decoded, progressed=True)
 
     def run(self, max_steps: Optional[int] = None) -> Dict[int, List[int]]:
         """Serve until queue + slots drain, or ``max_steps`` decode

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List, Optional, Tuple
+from typing import List, Optional
 
 import numpy as np
 
@@ -50,10 +50,9 @@ class TelemetrySnapshot:
     # router's load signal should see, not just instantaneous depth)
     queue_wait_p50_ms: Optional[float] = None
     queue_wait_samples: int = 0
-    # per-step queued-depth history rollups (deterministic for a fixed
-    # trace: steps are logical, not wall clock)
+    # deepest queue recorded at the end of a step (deterministic for a
+    # fixed trace: steps are logical, not wall clock)
     queue_depth_max: int = 0
-    queue_depth_history: Tuple[int, ...] = ()
 
 
 class Telemetry:
@@ -74,7 +73,7 @@ class Telemetry:
         self.peak_kv_occupancy = 0.0
         self.ttft_s: List[float] = []
         self.queue_wait_s: List[float] = []
-        self.queue_depth_history: List[int] = []
+        self.queue_depth_max = 0
 
     def record_submit(self) -> None:
         self.submitted += 1
@@ -111,7 +110,8 @@ class Telemetry:
         self.prefill_chunks += prefill_chunks
         self.peak_kv_occupancy = max(self.peak_kv_occupancy, kv_occupancy)
         if queue_depth is not None:
-            self.queue_depth_history.append(int(queue_depth))
+            self.queue_depth_max = max(self.queue_depth_max,
+                                       int(queue_depth))
 
     def now(self) -> float:
         return self._clock()
@@ -157,9 +157,7 @@ class Telemetry:
             queue_wait_p50_ms=(float(np.percentile(qwait, 50)) * 1e3
                                if qwait.size else None),
             queue_wait_samples=int(qwait.size),
-            queue_depth_max=(max(self.queue_depth_history)
-                             if self.queue_depth_history else 0),
-            queue_depth_history=tuple(self.queue_depth_history),
+            queue_depth_max=self.queue_depth_max,
         )
 
 
@@ -231,7 +229,7 @@ def export_to_registry(snap: TelemetrySnapshot, registry=None,
     g("queue_wait_samples", snap.queue_wait_samples,
       "admissions behind the queue-wait percentiles")
     g("queue_depth_max", snap.queue_depth_max,
-      "max queued depth over the per-step history")
+      "max queued depth at the end of a step")
     return reg
 
 

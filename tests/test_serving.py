@@ -292,9 +292,9 @@ def test_fcfs_head_of_line_blocks_smaller_requests():
 
 
 def test_queue_wait_telemetry_and_depth_history(tiny_params):
-    """Scheduling delay (submit -> admit) and per-step queue depth are
-    recorded: batch of 1 makes the waits strictly staircase and the
-    depth history deterministic."""
+    """Scheduling delay (submit -> admit) and the deepest per-step
+    queue are recorded: batch of 1 makes the waits strictly staircase
+    and the depths deterministic."""
     srv = _server(tiny_params, batch_size=1, max_len=32, num_blocks=17)
     for rid in range(3):
         srv.submit(_req(rid, max_new=2))
@@ -304,11 +304,8 @@ def test_queue_wait_telemetry_and_depth_history(tiny_params):
     assert snap.queue_wait_p50_ms is not None
     assert snap.queue_wait_p50_ms >= 0.0
     # queue depth: starts at 2 waiting (one admitted), drains to 0
-    assert snap.queue_depth_history[0] == 2
     assert snap.queue_depth_max == 2
-    assert snap.queue_depth_history[-1] == 0
-    hist = list(snap.queue_depth_history)
-    assert hist == sorted(hist, reverse=True), "depth must only drain"
+    assert snap.queue_depth == 0
 
     from repro.obs.registry import MetricsRegistry
     from repro.serving.telemetry import export_to_registry

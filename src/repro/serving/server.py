@@ -354,7 +354,11 @@ class ContinuousBatchingServer:
             args = self._decode_args(running)
         rows = [i for i, _ in running]
         reqs = [r for _, r in running]
-        with _span("serve.decode", rows=len(rows), batch=self.batch):
+        # the pages the decode kernel fetches per layer: each row's
+        # context with the token this step appends
+        kv_pages = sum(-(-(r.ctx_len + 1) // self.block_size) for r in reqs)
+        with _span("serve.decode", rows=len(rows), batch=self.batch,
+                   kv_pages=kv_pages):
             with self._on_mesh():
                 logits, self.pages = self._decode_fn(self.params,
                                                      self.pages, *args)

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import paged_attention, tile_pages
 from repro.kernels.ref import paged_attention_ref
 from repro.models import (decode_step, decode_step_paged, forward_paged,
                           forward_train, init_pages, init_params, prefill)
@@ -26,24 +26,65 @@ def _kernel(q, kp, vp, bt, lengths):
 
 
 # ------------------------- kernel vs oracle --------------------------- #
-@pytest.mark.parametrize("b,h,hkv,d,bs,n,m", [
-    (2, 4, 2, 16, 8, 10, 3),
-    (3, 8, 1, 32, 16, 12, 2),     # MQA
-    (1, 4, 4, 64, 8, 6, 4),       # MHA (group = 1)
-    (4, 8, 2, 128, 16, 24, 5),
+def _tables_and_lengths(case, rng, b, bs, n, m, tile):
+    """Block tables [B, M] and lengths [B] for one named case; ``tile``
+    is the kernel's tokens per tile at these widths."""
+    ids = rng.permutation(np.arange(1, n))
+    bt = np.resize(ids[:b * m], (b, m)).astype(np.int32)
+    if case in ("random", "bf16_pool"):
+        lengths = rng.integers(1, m * bs + 1, size=(b,))
+        if case == "bf16_pool":
+            lengths[0] = m * bs
+    elif case == "idle_beside_long":      # an idle row reads scratch block 0
+        bt[0] = 0
+        lengths = [1, m * bs, m * bs - 3 * tile // 2]
+    elif case == "tile_edges":
+        lengths = [tile, tile + 1, 2 * tile + 1]
+    elif case == "m_not_multiple":        # M is not a whole number of tiles
+        assert m % (tile // bs)
+        lengths = [m * bs, m * bs - bs - 5, m * bs - tile]
+    elif case == "several_tiles":
+        lengths = [m * bs - 7]
+    elif case == "shared_pages":          # a shared prefix, a page twice
+        bt[1, :m // 2] = bt[0, :m // 2]
+        bt[2, 3] = bt[0, 5]
+        lengths = [m * bs, m * bs - 9, m * bs // 2 + 1]
+    else:
+        raise ValueError(case)
+    return jnp.asarray(bt), jnp.asarray(np.asarray(lengths, np.int32))
+
+
+_OLD = lambda *args: pytest.param(*args, "random", id="-".join(map(str, args)))
+
+
+@pytest.mark.parametrize("b,h,hkv,d,bs,n,m,case", [
+    _OLD(2, 4, 2, 16, 8, 10, 3),
+    _OLD(3, 8, 1, 32, 16, 12, 2),        # MQA
+    _OLD(1, 4, 4, 64, 8, 6, 4),          # MHA (group = 1)
+    _OLD(4, 8, 2, 128, 16, 24, 5),
+    (3, 4, 2, 64, 16, 160, 70, "idle_beside_long"),
+    (3, 8, 2, 64, 16, 160, 70, "tile_edges"),
+    (3, 8, 8, 32, 16, 160, 70, "m_not_multiple"),
+    (1, 4, 1, 128, 8, 240, 200, "several_tiles"),
+    (3, 8, 4, 32, 16, 100, 40, "shared_pages"),
+    (4, 8, 2, 128, 16, 160, 70, "bf16_pool"),
 ])
-def test_paged_kernel_matches_ref(b, h, hkv, d, bs, n, m):
+def test_paged_kernel_matches_ref(b, h, hkv, d, bs, n, m, case):
+    """f32 pools at f32 tolerance; a bf16 pool against the f32 oracle on
+    the same (bf16) values, within the output's bf16 rounding."""
     rng = np.random.default_rng(b * 31 + n)
-    q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n, bs, hkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n, bs, hkv, d)), jnp.float32)
-    ids = rng.permutation(np.arange(1, n))[:b * m]
-    bt = jnp.asarray(np.resize(ids, (b, m)).astype(np.int32))
-    lengths = jnp.asarray(rng.integers(1, m * bs + 1, size=(b,)), jnp.int32)
+    dtype = jnp.bfloat16 if case == "bf16_pool" else jnp.float32
+    q = jnp.asarray(rng.normal(size=(b, h, d))).astype(dtype)
+    kp = jnp.asarray(rng.normal(size=(n, bs, hkv, d))).astype(dtype)
+    vp = jnp.asarray(rng.normal(size=(n, bs, hkv, d))).astype(dtype)
+    tile = tile_pages(bs, hkv * d, dtype, m) * bs
+    bt, lengths = _tables_and_lengths(case, rng, b, bs, n, m, tile)
     got = _kernel(q, kp, vp, bt, lengths)
-    want = paged_attention_ref(q, kp, vp, bt, lengths)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+    f32 = lambda x: x.astype(jnp.float32)
+    want = paged_attention_ref(f32(q), f32(kp), f32(vp), bt, lengths)
+    tol = 1e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               rtol=tol, atol=tol)
 
 
 def test_paged_kernel_bf16():

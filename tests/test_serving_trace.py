@@ -129,8 +129,35 @@ def test_profiler_trace_holds_serve_spans(tiny_params, tmp_path):
     stats = {e.name: dict(e.stats) for e in events
              if e.name.startswith("serve.")}
     assert stats["serve.step"] == {"step": 2}
-    assert stats["serve.decode"] == {"rows": 1, "batch": 2}
+    # the row decodes its 11th token: ceil(11 / 4) pages
+    assert stats["serve.decode"] == {"rows": 1, "batch": 2, "kv_pages": 3}
     assert stats["serve.decode.batch"] == {"rows": 1}
+
+
+def test_decode_span_counts_kernel_pages(tiny_params, tracer):
+    """``kv_pages`` on ``serve.decode`` is the pages the decode kernel
+    reads per layer: ceil((ctx + 1) / block_size) over running rows,
+    read here from the context lengths the decode program is given."""
+    server = _server(tiny_params, batch_size=3)
+    seen = []
+    decode = server._decode_fn
+
+    def recording(params, pages, tokens, tables, ctx):
+        seen.append(np.asarray(ctx))
+        return decode(params, pages, tokens, tables, ctx)
+
+    server._decode_fn = recording
+    for rid, n in enumerate((3, 8, 13)):
+        server.submit(_req(rid, prompt=np.arange(n, dtype=np.int32),
+                           max_new=6))
+    server.run()
+    got = [s.args["kv_pages"] for s in tracer.spans
+           if s.name == "serve.decode"]
+    # a running row has prefilled its prompt, so ctx > 0; idle rows are 0
+    want = [int(sum(-(-(c + 1) // 4) for c in ctx if c > 0))
+            for ctx in seen]
+    assert got == want and len(got) >= 5
+    assert max(got) > min(got)
 
 
 def test_admit_time_between_arrival_and_first_token(tiny_params):

@@ -54,13 +54,18 @@ def _shape(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("h,hkv,d", [
-    (36, 36, 64),       # minicpm-2b: MHA, group 1
-    (32, 8, 128),       # GQA, group 4
+@pytest.mark.parametrize("h,hkv,d,b,m,n", [
+    pytest.param(36, 36, 64, 8, 64, 513, id="36-36-64"),  # MHA, group 1
+    pytest.param(32, 8, 128, 8, 64, 513, id="32-8-128"),  # GQA, group 4
+    # the benchmark cells' decode batches: minicpm-2b chat (B 32, max_len
+    # 4096, 860 pool blocks) and mistral-nemo-12b-d10 long-doc (B 16,
+    # max_len 9216, 5000 blocks)
+    (36, 36, 64, 32, 256, 860),
+    (32, 8, 128, 16, 576, 5000),
 ])
-def test_paged_kernel_compiles_for_v5e(one_chip, h, hkv, d):
+def test_paged_kernel_compiles_for_v5e(one_chip, h, hkv, d, b, m, n):
     from repro.kernels.paged_attention import paged_attention
-    b, n, bs, m = 8, 513, 16, 64
+    bs = 16
     compiled = jax.jit(functools.partial(
         paged_attention, interpret=False)).lower(
         _shape(one_chip, (b, h, d)),

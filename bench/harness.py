@@ -105,14 +105,39 @@ class CompileCounter:
 DRAIN_S = 60.0
 
 
-def build_server(cfg, params, server_conf: Dict):
+def data_mesh(devices):
+    """A ``data`` mesh over the run's chips, as ``launch/serve.py --dp``
+    builds it; None on one chip."""
+    if len(devices) < 2:
+        return None
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh((len(devices),), ("data",), devices=devices)
+
+
+def weight_sharding(devices):
+    """Where the weights are made: every chip of a data mesh holds the
+    whole model, as the server places it (None: the default device)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    mesh = data_mesh(devices)
+    return None if mesh is None else NamedSharding(mesh, PartitionSpec())
+
+
+def build_server(cfg, params, server_conf: Dict, devices):
+    """The server over ``devices``.  The configuration's ``batch`` and
+    ``kv_pool_blocks`` are one chip's share: over n chips the server
+    runs n x ``batch`` rows striped over a ``data`` mesh, and, as the
+    program replicates its block pool, every chip holds the one pool of
+    ``kv_pool_blocks`` that all rows share."""
     from repro.serving import ContinuousBatchingServer
 
     return ContinuousBatchingServer(
         cfg,
         params,
-        server_conf["batch"],
+        server_conf["batch"] * len(devices),
         max_len=server_conf["max_len"],
+        mesh=data_mesh(devices),
         block_size=server_conf["block_size"],
         num_blocks=server_conf["kv_pool_blocks"],
         prefill_chunk=server_conf["prefill_chunk"],
@@ -133,6 +158,36 @@ def warm_up(server, vocab: int, seed: int) -> None:
     server.submit(Request(rid=-2, prompt=b, max_new_tokens=2))
     while server.has_work():
         server.step_once()
+
+
+#: the rids of ``fill_rows``'s requests (the traffic's are 0..n-1)
+FILL_RID = 1 << 30
+#: how long ``fill_rows`` steps at most
+FILL_S = 120.0
+
+
+def fill_rows(server, prompt_len: int, new_tokens: int, vocab: int, seed: int, limit_s: float = FILL_S) -> List:
+    """After the window, with nothing more due: one request for every
+    row of the server (``prompt_len`` token ids drawn from ``seed``,
+    ``new_tokens`` greedy outputs), all submitted at once, stepped
+    until each is done or ``limit_s`` has passed.  Over a ``data`` mesh
+    the window's load need not reach every chip's rows; these requests
+    do, through the same server and its compiled programs.  Returns
+    (the rows a request sat in, the request) for each finished one."""
+    from repro.serving import Request
+
+    rng = np.random.default_rng(seed)
+    reqs = [Request(rid=FILL_RID + i, prompt=rng.integers(0, vocab, size=prompt_len).astype(np.int32), max_new_tokens=new_tokens) for i in range(server.batch)]
+    for req in reqs:
+        server.submit(req)
+    rows: Dict[int, set] = {req.rid: set() for req in reqs}
+    end = time.monotonic() + limit_s
+    while not all(req.done for req in reqs) and time.monotonic() < end:
+        server.step_once()
+        for i, req in enumerate(server.scheduler.slots):
+            if req is not None and req.rid in rows:
+                rows[req.rid].add(i)
+    return [(sorted(rows[req.rid]), req) for req in reqs if req.done]
 
 
 def longest_steps(window: Window, n: int = 3) -> str:

@@ -22,12 +22,14 @@ SERVE = "serve."
 class Spans:
     """The traced window ``[lo, hi]`` (first to last ``bench.step``, ns),
     the ``serve.*`` host events inside it as (name, start, end, stats)
-    by start, and the first chip's busy intervals inside it."""
+    by start, the first chip's busy intervals inside it, and each chip's
+    device ops inside it as (HLO op name, start, end)."""
 
     lo: int
     hi: int
     events: List[Tuple[str, int, int, Dict]]
     busy: List[Tuple[int, int]]
+    chip_ops: List[List[Tuple[str, int, int]]]
 
     def named(self, name: str):
         return [e for e in self.events if e[0] == name]
@@ -63,9 +65,12 @@ def from_planes(planes) -> Optional[Spans]:
     serve = [(n, a, b, dict(getattr(e, "stats", ()))) for n, a, b, e in evs if n.startswith(SERVE) and lo <= a and b <= hi]
     if not serve:
         return None
-    ops = [(e.start_ns, e.start_ns + e.duration_ns) for ln in devs[0].lines if ln.name == tr.OPS_LINE for e in ln.events]
-    busy = tr.union([c for a, b in ops if (c := tr.clip(int(a), int(b), lo, hi))])
-    return Spans(lo, hi, sorted(serve, key=lambda e: (e[1], -e[2])), busy)
+    chip_ops = []
+    for p in devs:
+        evs = ((e.name, int(e.start_ns), int(e.start_ns + e.duration_ns)) for ln in p.lines if ln.name == tr.OPS_LINE for e in ln.events)
+        chip_ops.append([(tr.op_name(n),) + c for n, a, b in evs if (c := tr.clip(a, b, lo, hi))])
+    busy = tr.union([(a, b) for _, a, b in chip_ops[0]])
+    return Spans(lo, hi, sorted(serve, key=lambda e: (e[1], -e[2])), busy, chip_ops)
 
 
 @functools.lru_cache(maxsize=2)

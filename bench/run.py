@@ -4,10 +4,13 @@
 
 ``BENCHMARK.json`` names the cell; the cell names a configuration file
 (``bench/configs/<config>.json``) and a traffic file
-(``bench/traffic/<traffic>.json``); each metric the cell reports is read
-by ``bench/metrics/<metric>.py``; the limits of the correctness check
-are in ``bench/limits/<cell>.json``.  A new cell, configuration, mix or
-metric is a new file and a new entry, never an edit here.
+(``bench/traffic/<traffic>.json``); the configuration names the family
+module that holds its equations (``bench/families/<family>.py``); each
+metric the cell reports is read by ``bench/metrics/<metric>.py``; the
+limits of the correctness check are in ``bench/limits/<cell>.json``.  A
+new cell, configuration, family, mix or metric is a new file and a new
+entry, never an edit here.  A cell on several chips serves over a
+``data`` mesh of them.
 
 The run exits non-zero, printing no result, where JAX finds no TPU or
 fewer chips than the cell asks for.  It prints how late the generator

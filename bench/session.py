@@ -13,7 +13,7 @@ from typing import Callable, Dict, Optional
 
 import numpy as np
 
-from bench import check, gen, harness, model
+from bench import check, families, gen, harness
 from bench.peaks import Peaks, peaks
 
 METRICS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "metrics")
@@ -54,25 +54,27 @@ def counts(window: harness.Window, backlog: bool):
     return len(spans), sum(1 for s in spans if not s.tokens)
 
 
-def serve(conf, spec, limits, seed, seconds, log, trace_dir=None, marks=None):
-    """Set-up and window of one run: weights from the seed, the server,
-    its warm-up, the traffic, then ``seconds`` of open-loop serving and
-    the drain.  Returns (weights, window, server); ``marks`` (a list)
-    gets (phase, host time) as each set-up phase ends."""
+def serve(conf, spec, limits, seed, seconds, log, devices, trace_dir=None, marks=None):
+    """Set-up and window of one run on ``devices``: weights from the
+    seed, the server, its warm-up, the traffic, then ``seconds`` of
+    open-loop serving and the drain.  Returns (weights, window,
+    server); ``marks`` (a list) gets (phase, host time) as each set-up
+    phase ends."""
     import jax
 
     mark = (lambda name: marks.append((name, time.monotonic()))) if marks is not None else (lambda name: None)
-    cfg = model.arch_config(conf)
-    weights = model.make_weights(conf, seed)
+    fam = families.of(conf)
+    cfg = fam.arch_config(conf)
+    weights = fam.make_weights(conf, seed, harness.weight_sharding(devices))
     jax.block_until_ready(weights)
     mark("weights")
-    model.check_layout(weights, cfg)
-    server = harness.build_server(cfg, weights, conf["server"])
+    families.check_layout(weights, cfg)
+    server = harness.build_server(cfg, weights, conf["server"], devices)
     jax.block_until_ready(server.pages)
     mark("server")
     pool = sum(x.nbytes for x in jax.tree.leaves(server.pages))
     wbytes = sum(x.nbytes for x in jax.tree.leaves(weights))
-    log(f"{conf['name']}: weights {wbytes / 2**30:.3f} GiB, KV pool {server.allocator.num_blocks} blocks x {server.block_size} = {pool / 2**30:.3f} GiB")
+    log(f"{conf['name']}: weights {wbytes / 2**30:.3f} GiB, KV pool {server.allocator.num_blocks} blocks x {server.block_size} = {pool / 2**30:.3f} GiB, {server.batch} rows over {len(devices)} chip(s)")
     harness.warm_up(server, cfg.vocab_size, seed ^ 0x5EED)
     mark("warm-up")
     traffic = gen.generate(spec, seed, seconds, cfg.vocab_size)
@@ -102,6 +104,27 @@ def serve(conf, spec, limits, seed, seconds, log, trace_dir=None, marks=None):
     return weights, window, server
 
 
+#: salt of the seed that draws ``harness.fill_rows``'s prompts
+FILL_SALT = 0xF111
+
+
+def to_compare(server, window, spec, seed, limits, chips: int, log):
+    """The requests the check compares, as (the window's sample
+    (``check.sample_finished``), the fill's, the chips whose rows made
+    the fill's tokens): over several chips the fill's are one request of
+    ``harness.fill_rows`` from each chip's rows, at the mix's shortest
+    prompt and output; on one chip there are none, and no count (None)."""
+    finished = [r for r in window.requests if r.done]
+    sample = check.sample_finished(finished, seed, limits["min_tokens"], limits["max_requests"])
+    if chips < 2:
+        return sample, [], None
+    t = time.monotonic()
+    rows = harness.fill_rows(server, spec["prompt"]["min"], spec["output"]["min"], server.cfg.vocab_size, seed ^ FILL_SALT)
+    picked = check.one_per_chip(rows, server.batch, chips, seed)
+    log(f"rows filled: {len(rows)} of {server.batch} requests finished in {time.monotonic() - t:.1f} s; compared from chips {[c for c, _ in picked]}, rows {[rs for c, r in picked for rs, q in rows if q is r]}")
+    return sample, list({id(r): r for _, r in picked}.values()), len(picked)
+
+
 def run_cell(conf, spec, limits, metrics, seed, seconds, trace, devices, setup_t0, cache_dir, trace_dir, log) -> Dict:
     """One run; ``setup_t0`` is when JAX held the chip, where ``setup_s``
     starts."""
@@ -109,22 +132,22 @@ def run_cell(conf, spec, limits, metrics, seed, seconds, trace, devices, setup_t
     pk = peaks(dev.device_kind)
     log(f"compile cache {cache_dir}")
     marks = []
-    weights, window, server = serve(conf, spec, limits, seed, seconds, log, trace_dir if trace else None, marks)
+    weights, window, server = serve(conf, spec, limits, seed, seconds, log, devices, trace_dir if trace else None, marks)
     marks.append(("window", window.t0))
     log("set-up phases, s from the chip held: " + ", ".join(f"{name} {t - setup_t0:.3f}" for name, t in marks))
     setup_s = window.t0 - setup_t0
+    sample, fill, chips = to_compare(server, window, spec, seed, limits, len(devices), log)
+    sample += fill
     stats = [d.memory_stats() or {} for d in devices]
     peak = max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
     log(f"peak_bytes_in_use {peak} ({peak / 2**30:.3f} GiB) of bytes_limit {stats[0].get('bytes_limit')}")
-    finished = [r for r in window.requests if r.done]
     del server
     gc.collect()
 
     t = time.monotonic()
-    sample = check.sample_finished(finished, seed, limits["min_tokens"], limits["max_requests"])
-    cmp = check.compare(weights, conf, model.factors(conf), sample, limits["max_served"])
+    cmp = check.compare(weights, conf, sample, limits["max_served"])
     log(f"reference over {cmp['requests']} requests ({sum(len(r.prompt) + len(r.out) for r in sample)} tokens), {cmp['tokens']} served tokens compared in {time.monotonic() - t:.1f} s; {cmp['off_best']} tokens off the reference's best")
-    correct, checks = check.verdict(cmp["widest_gap"], cmp["tokens"], limits)
+    correct, checks = check.verdict(cmp["widest_gap"], cmp["tokens"], limits, None if chips is None else (chips, len(devices)))
 
     device = {"platform": dev.platform, "kind": dev.device_kind, "count": len(devices), "memory_peak_bytes": peak}
     summary = None

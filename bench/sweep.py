@@ -31,14 +31,15 @@ def main(argv=None) -> int:
     cell = bench_run.find(bench["workloads"], args.workload, "workload")
     conf = bench_run.load_json("bench", "configs", f"{cell['config']}.json")
     spec = bench_run.load_json("bench", "traffic", f"{cell['traffic']}.json")
-    bench_run.require_chips(cell["chips"])
+    devices = bench_run.require_chips(cell["chips"])
     bench_run.enable_cache()
-    from bench import gen, harness, model
+    from bench import families, gen, harness
     from bench.metrics import _common
 
-    cfg = model.arch_config(conf)
-    weights = model.make_weights(conf, args.seed)
-    server = harness.build_server(cfg, weights, conf["server"])
+    fam = families.of(conf)
+    cfg = fam.arch_config(conf)
+    weights = fam.make_weights(conf, args.seed, harness.weight_sharding(devices))
+    server = harness.build_server(cfg, weights, conf["server"], devices)
     harness.warm_up(server, cfg.vocab_size, args.seed)
     for rate in [float(r) for r in args.rates.split(",")]:
         traffic = gen.generate(dict(spec, rate_rps=rate), args.seed, args.seconds, cfg.vocab_size)

@@ -4,27 +4,16 @@ sound program is correct, and the timed path broken underneath makes
 
 import pytest
 
-from bench import harness
+from bench import faults, harness
 from bench.tests.tiny import run_tiny, tiny_conf, tiny_traffic
 
 
 def broken(monkeypatch, fault):
     build = harness.build_server
 
-    def build_broken(cfg, params, server_conf):
-        server = build(cfg, params, server_conf)
-        if fault == "token_altered":
-            greedy = server._greedy_fn
-            vocab = cfg.vocab_size
-            server._greedy_fn = lambda lg: (greedy(lg) + 1) % vocab
-        elif fault == "state_unchanged":
-            decode = server._decode_fn
-
-            def decode_unchanged(params, pages, *args):
-                logits, _ = decode(params, pages, *args)
-                return logits, pages  # the KV writes of the step dropped
-
-            server._decode_fn = decode_unchanged
+    def build_broken(cfg, params, server_conf, devices):
+        server = build(cfg, params, server_conf, devices)
+        faults.plant(server, fault)
         return server
 
     monkeypatch.setattr(harness, "build_server", build_broken)

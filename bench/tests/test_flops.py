@@ -3,7 +3,8 @@
 import pytest
 
 from bench import flops
-from bench.model import load_config
+from bench.families import dense
+from bench.tests.tiny import load_config
 
 # per layer: q and o projections, k and v projections, three MLP mats
 HAND = {
@@ -24,9 +25,9 @@ HAND = {
 def test_model_counts(name):
     conf = load_config(name)
     hand = HAND[name]
-    assert flops.layer_matmul_params(conf) == hand["layer"]
-    assert flops.dense_flops_per_token(conf) == hand["dense_token"]
-    assert flops.attention_flops(conf, 1) == hand["attn_position"]
+    assert dense.layer_matmul_params(conf) == hand["layer"]
+    assert dense.flops_per_token(conf) == hand["dense_token"]
+    assert dense.attention_flops(conf, 1) == hand["attn_position"]
     assert hand["dense_token"] == {"minicpm-2b": 5_449_388_544, "mistral-nemo-12b-d10": 6_794_772_480}[name]
 
 

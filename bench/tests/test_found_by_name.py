@@ -7,8 +7,7 @@ import os
 import shutil
 
 from bench import session
-from bench.model import BENCH_DIR
-from bench.tests.tiny import run_tiny, tiny_conf, tiny_traffic
+from bench.tests.tiny import BENCH_DIR, run_tiny, tiny_conf, tiny_traffic
 
 import importlib.util
 

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from bench import gen
-from bench.model import BENCH_DIR  # noqa: F401  (bench importable)
+from bench.tests.tiny import BENCH_DIR
 
 import json
 import os

@@ -109,3 +109,86 @@ def test_readers_none_without_serving_spans(monkeypatch):
     for name in READERS:
         assert session.load_reader(name)(fake_run([old])) is None, name
         assert session.load_reader(name)(fake_run([old], trace=False)) is None, name
+
+
+def four_chip_planes():
+    """Two traced decode steps and a prefill-only step on four chips.
+    Chip i's collectives: step 0 an async all-gather over [1300, 1400 +
+    10 i] and an all-reduce nested in a ``while``, 120 + 10 i ns; step
+    1 an overlapping collective-permute pair, 100 ns, and on chip 0 a
+    reduce-scatter, 40 ns more; an all-to-all in the prefill step and
+    one between steps are not counted."""
+    host = Plane(
+        "/host:CPU",
+        [
+            Line(
+                "python",
+                [
+                    ev("bench.step", 1000, 2000),
+                    ev("serve.step", 1000, 1900, step=0),
+                    ev("serve.decode", 1200, 1300, rows=3, batch=8),
+                    ev("bench.step", 2500, 3000),
+                    ev("serve.step", 2500, 2950, step=1),
+                    ev("serve.decode", 2600, 2650, rows=5, batch=8),
+                    ev("serve.step", 2955, 2990, step=2),
+                    ev("serve.prefill", 2955, 2980, rid=9, tokens=16, ctx=0),
+                ],
+            )
+        ],
+    )
+    chips = []
+    for i in range(4):
+        ops = [
+            Ev("all-gather-start.1", 1300, 20),
+            Ev("all-gather-done.1", 1320, 80 + 10 * i),
+            Ev("fusion.2", 1400 + 10 * i, 100 - 10 * i),
+            Ev("while.1", 1450, 150),
+            Ev("all-reduce.3", 1500, 20),
+            Ev("all-to-all.6", 2100, 100),
+            Ev("collective-permute-start.4", 2700, 50),
+            Ev("collective-permute-done.4", 2740, 60),
+            Ev("paged_attention.9", 2650, 50),
+            Ev("all-to-all.7", 2960, 10),
+        ]
+        if i == 0:
+            ops.append(Ev("reduce-scatter.5", 2800, 40))
+        chips.append(Plane(f"/device:TPU:{i}", [Line("XLA Ops", ops)]))
+    return [host] + chips
+
+
+def test_dp_collective_ms_on_four_chips(monkeypatch):
+    monkeypatch.setattr(_spans, "spans", lambda run: _spans.from_planes(four_chip_planes()))
+    run = fake_run()
+    run.trace = types.SimpleNamespace(devices=4)
+    # step 0: mean of 120..150 = 135 ns; step 1: (140 + 3 * 100) / 4 = 110 ns
+    assert session.load_reader("dp_collective_ms")(run) == pytest.approx((135 + 110) / 2 / 1e6)
+    # one chip of the four: 120 and 140 ns
+    run.trace = types.SimpleNamespace(devices=1)
+    assert session.load_reader("dp_collective_ms")(run) == pytest.approx((120 + 140) / 2 / 1e6)
+
+
+def test_dp_collective_ms_none_without_collectives(hand_built):
+    run = fake_run()
+    run.trace = types.SimpleNamespace(devices=1)
+    assert session.load_reader("dp_collective_ms")(run) is None
+
+
+def test_paged_attn_roofline_counts_every_chips_kernel_time():
+    from bench import flops, trace_reduce
+    from bench.tests.tiny import load_config
+    from bench.peaks import PEAKS
+
+    planes = four_chip_planes()
+    summary = trace_reduce.reduce_planes(planes, 4)
+    assert summary.kernel_seconds("paged_attention") == pytest.approx(50e-9)  # averaged over the chips
+    conf = load_config("minicpm-2b")
+    lengths = [100, 300, 17, 40, 1]  # the decode rows of all four chips
+    step = harness.StepSpan(0.0, 1.0, decode=lengths, traced=True)
+    w = harness.Window(0.0, 10.0, [step], {}, [], 0, 0.0)
+    run = types.SimpleNamespace(window=w, trace=summary, conf=conf, server=conf["server"], peaks=PEAKS["TPU v5 lite"])
+    fl, by = flops.paged_kernel_cost(conf, lengths, 16)
+    least = max(fl / run.peaks.flops, by / run.peaks.hbm_bytes_s)
+    assert session.load_reader("paged_attn_roofline")(run) == pytest.approx(100 * least / (4 * 50e-9))
+    # the same rows on one chip
+    run.trace = trace_reduce.reduce_planes(planes, 1)
+    assert session.load_reader("paged_attn_roofline")(run) == pytest.approx(100 * least / 50e-9)

@@ -8,7 +8,15 @@ import time
 
 from bench import peaks as peaks_mod
 from bench import session
-from bench.model import BENCH_DIR, load_config
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def load_config(name):
+    """``bench/configs/<name>.json``."""
+    with open(os.path.join(BENCH_DIR, "configs", f"{name}.json")) as f:
+        return json.load(f)
+
 
 TINY_WIDTHS = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4, num_key_value_heads=4, num_hidden_layers=2, vocab_size=256)
 TINY_LIMITS = {"widest_logit_gap": 0.006, "min_tokens": 96, "max_requests": 6, "max_served": 64}
@@ -30,11 +38,12 @@ def tiny_traffic(name):
     return s
 
 
-def run_tiny(conf, spec, metrics=(), seed=5, seconds=3.0, limits=TINY_LIMITS, monkeypatch=None):
-    """``session.run_cell`` on the CPU: the chip look is skipped and the
-    peaks are the v5e's (no number here is a device number)."""
+def run_tiny(conf, spec, metrics=(), seed=5, seconds=3.0, limits=TINY_LIMITS, monkeypatch=None, chips=1):
+    """``session.run_cell`` on the CPU over ``chips`` devices: the chip
+    look is skipped and the peaks are the v5e's (no number here is a
+    device number)."""
     import jax
 
     if monkeypatch is not None:
         monkeypatch.setattr(session, "peaks", lambda kind: peaks_mod.PEAKS["TPU v5 lite"])
-    return session.run_cell(conf, spec, limits, [{"name": m, "unit": "x"} for m in metrics], seed, seconds, False, jax.devices()[:1], time.monotonic(), "unused", "unused", lambda m: None)
+    return session.run_cell(conf, spec, limits, [{"name": m, "unit": "x"} for m in metrics], seed, seconds, False, jax.devices()[:chips], time.monotonic(), "unused", "unused", lambda m: None)
